@@ -29,6 +29,7 @@ import hmac
 import json
 import logging
 import os
+import threading
 import time
 import uuid
 from pathlib import Path
@@ -89,6 +90,12 @@ HAD_SESSION_CAP = 4096
 #: default) left the first live size-2/4 flush eating a cold jit inside
 #: KEY_EXCHANGE_TIMEOUT
 WARMUP_SIZES = (1, 2, 4)
+#: serialises the background warmups of every engine in the process: two
+#: engines (a hub and its client plane, a task-mode fleet) warm the same
+#: programs, and tracing and lowering them holds the GIL — warming both at
+#: once takes as long as warming them one after the other, twice over,
+#: where the second warmup alone finds every program already compiled
+_WARMUP_LOCK = threading.Lock()
 #: latency SLO threshold for an initiated handshake attempt (obs/slo.py):
 #: chosen ON a DEFAULT_LATENCY_BUCKETS boundary so the good/bad split of
 #: the burn-rate math is exact, and generous enough that only a degraded
@@ -1483,7 +1490,6 @@ class SecureMessaging:
         AND after an algorithm hot-swap (only for the swapped provider — the
         other is already warm).  cpu-backend algorithms have no jit cache to
         warm, so they are skipped (their warmup would run real slow crypto)."""
-        import threading
 
         bkem = self._bkem if kem and getattr(self.kem, "backend", "") == "tpu" else None
         bsig = (
@@ -1498,35 +1504,38 @@ class SecureMessaging:
             return
 
         def _warm():
-            try:
-                # Device-health gate first (provider/health.py): validate the
-                # accelerated path for THIS environment before trusting it
-                # with live traffic — a failed family quarantines the shared
-                # breaker onto the cpu fallback, and HQC re-routes its FFT.
-                from ..provider import health
+            # one engine compiles at a time (_WARMUP_LOCK): the next one
+            # finds every shared program in the cache
+            with _WARMUP_LOCK:
+                try:
+                    # Device-health gate first (provider/health.py): validate the
+                    # accelerated path for THIS environment before trusting it
+                    # with live traffic — a failed family quarantines the shared
+                    # breaker onto the cpu fallback, and HQC re-routes its FFT.
+                    from ..provider import health
 
-                health.gate_facades(bkem, bsig, bfused, baead)
-                first = bkem or bsig or bfused or baead
-                if first is not None and first.breaker.state == "quarantined":
-                    # the facades share one breaker: a quarantine pins the
-                    # cpu fallback for the process, so compiling the device
-                    # buckets would burn minutes for a path that can never
-                    # serve traffic
-                    logger.warning(
-                        "device path quarantined by the health gate; "
-                        "skipping device warmup"
-                    )
-                    return
-                if bkem is not None:
-                    bkem.warmup(WARMUP_SIZES)
-                if bsig is not None:
-                    bsig.warmup(WARMUP_SIZES)
-                if bfused is not None:
-                    bfused.warmup(WARMUP_SIZES)
-                if baead is not None:
-                    baead.warmup(WARMUP_SIZES)
-            except Exception:
-                logger.exception("batched-provider warmup failed")
+                    health.gate_facades(bkem, bsig, bfused, baead)
+                    first = bkem or bsig or bfused or baead
+                    if first is not None and first.breaker.state == "quarantined":
+                        # the facades share one breaker: a quarantine pins the
+                        # cpu fallback for the process, so compiling the device
+                        # buckets would burn minutes for a path that can never
+                        # serve traffic
+                        logger.warning(
+                            "device path quarantined by the health gate; "
+                            "skipping device warmup"
+                        )
+                        return
+                    if bkem is not None:
+                        bkem.warmup(WARMUP_SIZES)
+                    if bsig is not None:
+                        bsig.warmup(WARMUP_SIZES)
+                    if bfused is not None:
+                        bfused.warmup(WARMUP_SIZES)
+                    if baead is not None:
+                        baead.warmup(WARMUP_SIZES)
+                except Exception:
+                    logger.exception("batched-provider warmup failed")
 
         self._warmup_thread = threading.Thread(
             target=_warm, name="qrp2p-warmup", daemon=True

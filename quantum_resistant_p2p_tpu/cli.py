@@ -181,21 +181,15 @@ class CLI:
                        f"http://127.0.0.1:{self.messaging.telemetry_port} "
                        "(/metrics /healthz /readyz /slo /trace /cost)")
         self.secure_logger.log_event("initialization", node_id=node_id, port=self.node.port)
-        # Explicit native-core availability, the role of the reference's
+        # Explicit native-core status, the role of the reference's
         # status-bar OQS chip (ui/oqs_status_widget.py:29-31).  load() may
         # run a first-launch g++ build, so keep it off the event loop — the
-        # TCP server and discovery are already serving.
+        # TCP server and discovery are already serving.  A failed build
+        # raises: there is no pure-Python fallback to advertise.
         def _probe_native() -> str:
-            try:
-                from . import native
+            from . import native
 
-                if native.load() is not None:
-                    return "native C++ core: ✓"
-            except Exception:
-                # The fallback banner already tells the user; keep the cause
-                # findable instead of silently discarding it.
-                logger.debug("native core probe failed", exc_info=True)
-            return "native C++ core: ✗ (pure-Python fallback)"
+            return f"native C++ core: v{native.load().qrp_version()}"
 
         core = await asyncio.get_running_loop().run_in_executor(None, _probe_native)
         self.print(f"node {node_id[:12]}… listening on :{self.node.port} "
@@ -542,6 +536,11 @@ def main(argv: list[str] | None = None) -> int:
         use_batching=True if args.batch else None,
         mesh_devices=args.mesh_devices,
     )
+    if cfg.backend != "cpu":
+        # XLA compiles each device program once per cache, not per start
+        from .utils.compile_cache import enable_compile_cache
+
+        enable_compile_cache()
 
     logging.basicConfig(
         level=getattr(logging, args.log_level.upper(), logging.INFO),

@@ -2,7 +2,7 @@
 
 The gather S-box (core/aes.py) is the canonical TPU anti-pattern: per-lane
 dynamic ``jnp.take`` serialises, and FrodoKEM-AES runs 2.6M of them per
-640x640 A-matrix (bench_report config 3: 15 encaps/s).  Bitslicing is the
+640x640 A-matrix (15 encaps/s on an earlier platform).  Bitslicing is the
 canonical counter: the state is held as 128 bit-planes packed 32 blocks per
 uint32 lane, SubBytes becomes a boolean circuit evaluated on whole planes
 (pure AND/XOR — ideal VPU material), ShiftRows a static plane permutation,

@@ -24,8 +24,8 @@ AEAD MAC input is block-aligned by construction (§2.8 pads AAD and
 ciphertext to 16), which is what makes variable lengths maskable: inactive
 blocks leave the accumulator untouched via a per-lane select.
 
-Oracle: the pure-Python scalar twin (pyref/chacha_ref.py) and — when the
-OpenSSL wheel is present — the ``cryptography`` package;
+Oracle: the pure-Python scalar twin (pyref/chacha_ref.py) and the
+``cryptography`` package;
 tests/test_chacha_pallas.py pins the RFC 8439 §2.8.2 vector and every
 masked-tail bucket edge through both the jnp and (interpret-mode) Pallas
 paths.  Used by provider/aead_device.py behind the ``BatchedAEAD``
@@ -41,7 +41,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .keccak import _use_pallas
 
 #: ChaCha20 constants "expa" "nd 3" "2-by" "te k" (RFC 8439 §2.3)
 _CONSTS = (0x61707865, 0x3320646E, 0x79622D32, 0x6B206574)
@@ -368,8 +367,3 @@ def _le64(n: jax.Array) -> jax.Array:
     return jnp.concatenate([lo, jnp.zeros_like(lo)],
                            axis=-1).astype(jnp.uint8)
 
-
-def use_pallas_default() -> bool:
-    """Pallas fast path on real TPU; jnp twin elsewhere (core.keccak's
-    shared ``QRP2P_PALLAS`` policy — tests run interpret mode explicitly)."""
-    return _use_pallas()

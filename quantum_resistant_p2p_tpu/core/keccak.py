@@ -210,18 +210,11 @@ def _words_to_bytes(hi: jax.Array, lo: jax.Array) -> jax.Array:
 
 
 def _use_pallas() -> bool:
-    """Pallas fast path on real TPU; pure-jnp elsewhere (tests run on CPU)."""
-    import os
+    """Pallas kernels on a TPU, the jnp twins elsewhere (tests run on CPU).
 
-    flag = os.environ.get("QRP2P_PALLAS", "auto")
-    if flag == "0":
-        return False
-    if flag == "1":
-        return True
-    try:
-        return jax.default_backend() not in ("cpu",)
-    except Exception:  # pragma: no cover  # qrlint: disable=broad-except  — backend probe: jax without a functioning platform means "no TPU", the false return IS the handling
-        return False
+    Decided from the platform alone: a backend that cannot be probed
+    raises here rather than quietly selecting the jnp path."""
+    return jax.default_backend() == "tpu"
 
 
 def sponge(data: jax.Array, rate: int, ds_byte: int, out_len: int) -> jax.Array:

@@ -31,6 +31,8 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+from jax import lax
 from jax.experimental import pallas as pl
 
 from .keccak import _PI_SRC, _RC, _RHO
@@ -44,6 +46,16 @@ _TS, _TL = 8, 128
 BT = _TS * _TL
 
 
+# The round function is written in lax primitives, not jnp operators: each
+# jnp operator on a tracer goes through a jit of its own, and a sign
+# program traces ~10k of them per permutation -- most of its trace time.
+# The jaxpr is the same either way.
+_xor = lax.bitwise_xor
+_and = lax.bitwise_and
+_or = lax.bitwise_or
+_not = lax.bitwise_not
+
+
 def _rotl(hi, lo, n: int):
     n %= 64
     if n == 0:
@@ -53,9 +65,10 @@ def _rotl(hi, lo, n: int):
         n -= 32
         if n == 0:
             return hi, lo
+    up, down = np.uint32(n), np.uint32(32 - n)
     return (
-        (hi << n) | (lo >> (32 - n)),  # qrkernel: wrapping — uint32 lane words: bits shifted past 32 drop by design, the rotation recovers them from the partner word
-        (lo << n) | (hi >> (32 - n)),  # qrkernel: wrapping — same wrap-by-design rotation, low word
+        _or(lax.shift_left(hi, up), lax.shift_right_logical(lo, down)),  # qrkernel: wrapping — uint32 lane words: bits shifted past 32 drop by design, the rotation recovers them from the partner word
+        _or(lax.shift_left(lo, up), lax.shift_right_logical(hi, down)),  # qrkernel: wrapping — same wrap-by-design rotation, low word
     )
 
 
@@ -63,14 +76,16 @@ def _f1600(sh: list, sl: list) -> tuple[list, list]:
     """One Keccak-f[1600] permutation over 50 (8, 128) uint32 tiles."""
     for rnd in range(24):
         # theta
-        ch = [sh[x] ^ sh[x + 5] ^ sh[x + 10] ^ sh[x + 15] ^ sh[x + 20] for x in range(5)]
-        cl = [sl[x] ^ sl[x + 5] ^ sl[x + 10] ^ sl[x + 15] ^ sl[x + 20] for x in range(5)]
+        ch = [_xor(_xor(_xor(_xor(sh[x], sh[x + 5]), sh[x + 10]), sh[x + 15]), sh[x + 20])
+              for x in range(5)]
+        cl = [_xor(_xor(_xor(_xor(sl[x], sl[x + 5]), sl[x + 10]), sl[x + 15]), sl[x + 20])
+              for x in range(5)]
         for x in range(5):
             rh, rl = _rotl(ch[(x + 1) % 5], cl[(x + 1) % 5], 1)
-            dh, dl = ch[(x + 4) % 5] ^ rh, cl[(x + 4) % 5] ^ rl
+            dh, dl = _xor(ch[(x + 4) % 5], rh), _xor(cl[(x + 4) % 5], rl)
             for y in range(5):
-                sh[x + 5 * y] = sh[x + 5 * y] ^ dh
-                sl[x + 5 * y] = sl[x + 5 * y] ^ dl
+                sh[x + 5 * y] = _xor(sh[x + 5 * y], dh)
+                sl[x + 5 * y] = _xor(sl[x + 5 * y], dl)
         # rho + pi
         bh, bl = [None] * 25, [None] * 25
         for dst in range(25):
@@ -81,11 +96,11 @@ def _f1600(sh: list, sl: list) -> tuple[list, list]:
             row_h = [bh[x + 5 * y] for x in range(5)]
             row_l = [bl[x + 5 * y] for x in range(5)]
             for x in range(5):
-                sh[x + 5 * y] = row_h[x] ^ (~row_h[(x + 1) % 5] & row_h[(x + 2) % 5])
-                sl[x + 5 * y] = row_l[x] ^ (~row_l[(x + 1) % 5] & row_l[(x + 2) % 5])
+                sh[x + 5 * y] = _xor(row_h[x], _and(_not(row_h[(x + 1) % 5]), row_h[(x + 2) % 5]))
+                sl[x + 5 * y] = _xor(row_l[x], _and(_not(row_l[(x + 1) % 5]), row_l[(x + 2) % 5]))
         # iota
-        sh[0] = sh[0] ^ jnp.uint32(int(_RC[rnd, 0]))
-        sl[0] = sl[0] ^ jnp.uint32(int(_RC[rnd, 1]))
+        sh[0] = _xor(sh[0], np.uint32(int(_RC[rnd, 0])))
+        sl[0] = _xor(sl[0], np.uint32(int(_RC[rnd, 1])))
     return sh, sl
 
 
@@ -113,7 +128,8 @@ def block_bytes(sh: list, sl: list, rate_words: int) -> list:
     for w in range(rate_words):
         for b in range(8):
             word = sl[w] if b < 4 else sh[w]
-            byts.append((word >> (8 * (b % 4))) & 0xFF)
+            byts.append(_and(lax.shift_right_logical(word, np.uint32(8 * (b % 4))),
+                             np.uint32(0xFF)))
     return byts
 
 

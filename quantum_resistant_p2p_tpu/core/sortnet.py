@@ -17,6 +17,7 @@ from __future__ import annotations
 import numpy as np
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 
 def bitonic_sort(x: jax.Array) -> jax.Array:
@@ -48,7 +49,9 @@ def bitonic_sort_regs(regs: list) -> list:
     reshapes, rolls or gathers — which makes the helper usable inside Pallas
     TPU kernels where each list element is one resident vector tile
     (kem/mlkem_pallas.py keeps all 512 SampleNTT candidates in VMEM this way).
-    ``len(regs)`` must be a power of two.
+    ``len(regs)`` must be a power of two.  Written in lax primitives, not
+    jnp operators: a 1024-element network is ~56k of them, and each jnp
+    operator on a tracer goes through a jit of its own (same jaxpr).
     """
     n = len(regs)
     stages = int(np.log2(n))
@@ -61,8 +64,8 @@ def bitonic_sort_regs(regs: list) -> list:
                 p = i | d
                 if p == i:
                     continue
-                lo = jnp.minimum(regs[i], regs[p])
-                hi = jnp.maximum(regs[i], regs[p])
+                lo = lax.min(regs[i], regs[p])
+                hi = lax.max(regs[i], regs[p])
                 if (i >> k) & 1:
                     regs[i], regs[p] = hi, lo
                 else:
@@ -90,11 +93,12 @@ def bitonic_sort_pairs_regs(keys: list, vals: list) -> tuple[list, list]:
                 p = i | d
                 if p == i:
                     continue
-                swap = keys[i] > keys[p] if not ((i >> k) & 1) else keys[i] < keys[p]
-                ki = jnp.where(swap, keys[p], keys[i])
-                kp = jnp.where(swap, keys[i], keys[p])
-                vi = jnp.where(swap, vals[p], vals[i])
-                vp = jnp.where(swap, vals[i], vals[p])
+                swap = (lax.gt(keys[i], keys[p]) if not ((i >> k) & 1)
+                        else lax.lt(keys[i], keys[p]))
+                ki = lax.select(swap, keys[p], keys[i])
+                kp = lax.select(swap, keys[i], keys[p])
+                vi = lax.select(swap, vals[p], vals[i])
+                vp = lax.select(swap, vals[i], vals[p])
                 keys[i], keys[p] = ki, kp
                 vals[i], vals[p] = vi, vp
     return keys, vals

@@ -28,6 +28,14 @@ Abrupt death (SIGKILL from the chaos plan, or task cancellation) skips
 4-5 by construction — peers see a dropped TCP session, the router sees
 missed heartbeats, and the fleet handoff machinery takes over.
 
+One chip per process: a gateway on the real providers runs them on the
+device (the health gate alone initialises JAX's default backend), and a
+chip belongs to one process.  A parent that holds the chip refuses to
+spawn such gateway subprocesses (:func:`refuse_if_chip_held`) rather than
+let them fail or hang; ``spawn="task"`` runs a whole fleet in the one
+process that holds the chip.  Stdlib-provider gateways never touch the
+device and spawn anywhere.
+
 HA control plane (docs/fleet.md): when the config carries a ``routers``
 list instead of the single ``router_host``/``router_port`` pair, the
 gateway maintains ONE control link PER router replica — hello +
@@ -205,16 +213,10 @@ async def run_gateway(cfg: dict[str, Any]) -> None:
             kem_name, sig_name = "STORM-KEM", "STORM-SIG"
             aead: Any = StormAEAD()
         else:
-            kem_name, sig_name = "ML-KEM-768", "ML-DSA-65"
-            try:
-                from ..provider import get_symmetric
+            from ..provider import get_symmetric
 
-                aead = get_symmetric("AES-256-GCM")
-            except Exception:
-                logger.warning("gateway %s: real AEAD unavailable, "
-                               "degrading to the stdlib storm AEAD", gid,
-                               exc_info=True)
-                aead = StormAEAD()
+            kem_name, sig_name = "ML-KEM-768", "ML-DSA-65"
+            aead = get_symmetric("AES-256-GCM")
         node = P2PNode(node_id=gid, host=str(cfg["bind_host"]), port=0,
                        max_peers=int(cfg["max_peers"]))
         await node.start()
@@ -475,6 +477,29 @@ async def run_gateway(cfg: dict[str, Any]) -> None:
             for w in writers.values():
                 w.close()
             await node.stop()
+
+
+def refuse_if_chip_held(providers: str) -> None:
+    """Raise when THIS process holds an accelerator and the gateway
+    subprocess about to be spawned would need it (it would fail or hang: a
+    chip belongs to one process).  ``providers="stdlib"`` gateways never
+    touch the device."""
+    if providers == "stdlib" or "jax" not in sys.modules:
+        return
+    # jax has no public probe that does not itself initialise a backend
+    # (which would take the chip from the child this guards)
+    from jax._src import xla_bridge
+
+    if not xla_bridge.backends_are_initialized():
+        return
+    import jax
+
+    platform = jax.default_backend()
+    if platform != "cpu":
+        raise RuntimeError(
+            f"this process holds the {platform} device; a gateway subprocess "
+            "cannot reach it (a chip belongs to one process) — run the "
+            "fleet in this process with spawn='task'")
 
 
 def main(argv: list[str] | None = None) -> int:

@@ -455,6 +455,9 @@ class GatewayFleet:
 
             member.task = asyncio.create_task(run_gateway(cfg))
             return
+        from .gateway import refuse_if_chip_held
+
+        refuse_if_chip_held(self.providers)
         stderr = asyncio.subprocess.DEVNULL
         log_f = None
         if self.report_dir is not None:

@@ -338,6 +338,9 @@ class RouterFleet:
             self._gw_tasks[gid] = asyncio.create_task(
                 run_gateway(cfg), name=f"gateway:{gid}")
             return
+        from .gateway import refuse_if_chip_held
+
+        refuse_if_chip_held(self.providers)
         stderr = asyncio.subprocess.DEVNULL
         log_f = None
         if self.report_dir is not None:

@@ -47,6 +47,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from ..core import keccak
 from ..core.keccak_pallas import _TL, _TS, BT, _f1600, absorb_block
@@ -60,12 +61,6 @@ _N_CHUNKS = 16  # twin row chunks (matches kem/frodo.py N_CHUNKS)
 def row_blocks(p: FrodoParams) -> int:
     """Squeeze blocks per A row: ceil(2n / 168) — 8 / 12 / 16."""
     return -(-2 * p.n // 168)
-
-
-def use_pallas_default() -> bool:
-    """Pallas kernel on real TPU, scanned-jnp twin elsewhere (the shared
-    ``QRP2P_PALLAS`` policy of core.keccak)."""
-    return keccak._use_pallas()
 
 
 def seed_words(p: FrodoParams, seed_a: jax.Array):
@@ -218,6 +213,24 @@ def _pad_lanes(x: jax.Array, b: int, bp: int) -> jax.Array:
     return jnp.pad(x, pad)
 
 
+def _seed_tiles(w: jax.Array, b: int, bp: int) -> jax.Array:
+    """(21, B) seed words -> (21, B/128, 1, 128): one (1, 128) row per lane
+    tile.  Mosaic wants a block's last two dims divisible by (8, 128) or
+    equal to the array's; a (21, 1, 128) block of a (21, B/128, 128) array
+    is neither, and the v5e compiler refuses it."""
+    return _pad_lanes(w, b, bp).reshape(RATE_WORDS, bp // _TL, 1, _TL)
+
+
+#: S'.A keeps an (NBAR, n, 128) accumulator and same-sized per-tile
+#: intermediates in VMEM: 25 MB at n = 640 against the 16 MB default scoped
+#: limit, which the v5e compiler refuses.  v5e has 128 MiB of VMEM.
+_VMEM_LIMIT = pltpu.CompilerParams(vmem_limit_bytes=100 * 2**20)
+
+#: the seed words' block: the lane tile's (1, 128) row, tile axis squeezed
+_SEED_SPEC = pl.BlockSpec((RATE_WORDS, pl.squeezed, 1, _TL),
+                          lambda bt, rc: (0, bt, 0, 0))
+
+
 @functools.partial(jax.jit,
                    static_argnames=("n", "q_mask", "n_sq", "interpret"))
 def s_times_a_words(in_hi: jax.Array, in_lo: jax.Array, sp: jax.Array, *,
@@ -232,20 +245,21 @@ def s_times_a_words(in_hi: jax.Array, in_lo: jax.Array, sp: jax.Array, *,
     """
     b = in_hi.shape[1]
     bp = -(-b // _TL) * _TL
-    in_hi = _pad_lanes(in_hi, b, bp).reshape(RATE_WORDS, bp // _TL, _TL)
-    in_lo = _pad_lanes(in_lo, b, bp).reshape(RATE_WORDS, bp // _TL, _TL)
+    in_hi = _seed_tiles(in_hi, b, bp)
+    in_lo = _seed_tiles(in_lo, b, bp)
     sp = _pad_lanes(sp, b, bp)
     kern = functools.partial(_s_times_a_kernel, n=n, q_mask=q_mask, n_sq=n_sq)
     out = pl.pallas_call(
         kern,
         grid=(bp // _TL, n // _TS),
         in_specs=[
-            pl.BlockSpec((RATE_WORDS, 1, _TL), lambda bt, rc: (0, bt, 0)),
-            pl.BlockSpec((RATE_WORDS, 1, _TL), lambda bt, rc: (0, bt, 0)),
+            _SEED_SPEC,
+            _SEED_SPEC,
             pl.BlockSpec((NBAR, _TS, _TL), lambda bt, rc: (0, rc, bt)),
         ],
         out_specs=pl.BlockSpec((NBAR, n, _TL), lambda bt, rc: (0, 0, bt)),
         out_shape=jax.ShapeDtypeStruct((NBAR, n, bp), jnp.int32),
+        compiler_params=_VMEM_LIMIT,
         interpret=interpret,
     )(in_hi, in_lo, sp)
     return out[..., :b]
@@ -264,16 +278,16 @@ def a_times_s_words(in_hi: jax.Array, in_lo: jax.Array, s: jax.Array, *,
     """
     b = in_hi.shape[1]
     bp = -(-b // _TL) * _TL
-    in_hi = _pad_lanes(in_hi, b, bp).reshape(RATE_WORDS, bp // _TL, _TL)
-    in_lo = _pad_lanes(in_lo, b, bp).reshape(RATE_WORDS, bp // _TL, _TL)
+    in_hi = _seed_tiles(in_hi, b, bp)
+    in_lo = _seed_tiles(in_lo, b, bp)
     s = _pad_lanes(s, b, bp)
     kern = functools.partial(_a_times_s_kernel, n=n, q_mask=q_mask, n_sq=n_sq)
     out = pl.pallas_call(
         kern,
         grid=(bp // _TL, n // _TS),
         in_specs=[
-            pl.BlockSpec((RATE_WORDS, 1, _TL), lambda bt, rc: (0, bt, 0)),
-            pl.BlockSpec((RATE_WORDS, 1, _TL), lambda bt, rc: (0, bt, 0)),
+            _SEED_SPEC,
+            _SEED_SPEC,
             pl.BlockSpec((n, NBAR, _TL), lambda bt, rc: (0, 0, bt)),
         ],
         out_specs=pl.BlockSpec((_TS, NBAR, _TL), lambda bt, rc: (rc, 0, bt)),

@@ -54,16 +54,10 @@ from ..pyref.hqc_ref import (
     _rs_gen_poly,
 )
 
-#: Single-dispatch batch cap (provider/base.py sliced_dispatch).  Round 2
-#: observed a 256-row keygen dispatch crashing the remote TPU worker; the
-#: round-3 bisection (tools/repro_worker_fault.py) found no deterministic
-#: fault (transient worker state), and the late-round FFT cyclic product
-#: shrank HQC's working set by orders of magnitude (33 MB spectra instead
-#: of the Toeplitz chunk expansion), removing the original caution's
-#: substance: batch 512 measured clean and ~8% faster than 128
-#: (bench_results/r3_hqc_fft_levels.json).  512 balances that against
-#: queue latency; the batched provider's cpu fallback + breaker absorb
-#: any transient recurrence.
+#: Single-dispatch batch cap (provider/base.py sliced_dispatch).  The FFT
+#: cyclic product keeps HQC's working set small (33 MB spectra instead of
+#: the Toeplitz chunk expansion); the cap dates from an earlier platform
+#: and awaits a sweep on the chip (ROADMAP queue 1 item 6).
 MAX_DEVICE_BATCH = 512
 
 _EXP = np.asarray(_GF_EXP, dtype=np.int32)  # length 512 (host-side table builds)
@@ -375,7 +369,7 @@ def _cyclic_mul_sparse(p: HQCParams, dense: jax.Array, sup: jax.Array) -> jax.Ar
 # on failure.  The verdict is cached per (jax version, jaxlib version,
 # device kind) in ~/.cache/qrp2p_tpu so the cost is once per environment,
 # not per process.  QRP2P_HQC_SELFCHECK=0 skips the gate (trust the FFT);
-# tools/check_pallas_device.py remains the manual on-chip A/B.
+# chip_smoke.py runs the same comparison on the chip.
 
 
 def _fft_selfcheck(p: HQCParams) -> tuple[bool, float]:

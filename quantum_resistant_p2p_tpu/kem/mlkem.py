@@ -47,14 +47,12 @@ from ..pyref.mlkem_ref import (  # parameter sets + computed constant tables
 Q = 3329
 N = 256
 
-#: Provider slice size: the per-dispatch scaling curve (bench_report.md)
-#: plateaus over 1024-2048 rows — the fused Pallas sampler kernels
+#: Provider slice size.  The fused Pallas sampler kernels
 #: (kem/mlkem_pallas.py) process exactly 1024 sponges per grid step, so
-#: smaller dispatches pad and waste tile lanes, while past 2048 the
-#: remaining jnp pipeline's working set spills (4096-row single dispatch:
-#: 733k encaps/s vs ~1M sliced).  2048 measures ~6% above 1024 and
-#: bench.py's raw-ops headline uses it; the provider takes the plateau's
-#: LOW end for queue latency.  Providers slice larger batches
+#: smaller dispatches pad and waste tile lanes; an earlier platform showed
+#: throughput flat over 1024-2048 rows and falling past 2048, where the
+#: remaining jnp pipeline's working set spills.  Not measured on this
+#: chip: the cap awaits a sweep there.  Providers slice larger batches
 #: (provider/base.py sliced_dispatch).
 MAX_DEVICE_BATCH = 1024
 _N_INV = 3303  # 128^-1 mod q

@@ -3,7 +3,8 @@
 Why: XLA cost analysis attributes ~85% of a batched encaps program's HBM
 traffic to SampleNTT — 3.52 of 4.14 GB per 512-batch (2.4 GB of it the
 bitonic compaction, the rest candidate extraction) — and the op is purely
-memory-bound (bench_report.md roofline).  This kernel runs the ENTIRE
+memory-bound (expected; the roofline share is not measured on this chip).
+This kernel runs the ENTIRE
 SampleNTT pipeline per seed — SHAKE-128 absorb, 4 squeeze permutations,
 byte-triple candidate extraction, rejection-key packing, and the 512-wide
 bitonic compaction network — inside one Pallas program with every
@@ -35,6 +36,8 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+from jax import lax
 
 from ..core.keccak_pallas import _f1600, absorb_block, block_bytes, sampler_call
 from ..core.sortnet import bitonic_sort_regs
@@ -190,6 +193,9 @@ from ..pyref.mlkem_ref import ZETAS as _ZETAS_PY
 
 _N = 256
 _N_INV = pow(128, -1, Q)  # 3303: ML-KEM's NTT has 128 base pairs, not 256 slots
+# the butterflies in lax primitives, not jnp operators, for trace time (see
+# core/keccak_pallas.py); the jaxpr is the same
+_Q = np.int32(Q)
 
 
 def _mul_zeta(a, z: int):
@@ -200,7 +206,7 @@ def _mul_zeta(a, z: int):
     machine-checked by qrkernel's interval analysis from the contracts."""
     # qrkernel: assume a in [0, Q) — FIPS 203 §4.3: butterfly operands are mod-q residues (every caller reduces % Q first)
     # qrkernel: assume z in [0, Q) — zeta table entries are powers of the 256th root of unity mod q
-    return (a * z) % Q
+    return lax.rem(lax.mul(a, np.int32(z)), _Q)
 
 
 def ntt_tiles(f: list) -> list:
@@ -216,7 +222,8 @@ def ntt_tiles(f: list) -> list:
             for j in range(length):
                 i0, i1 = base + j, base + length + j
                 t = _mul_zeta(f[i1], z)
-                f[i0], f[i1] = (f[i0] + t) % Q, (f[i0] - t) % Q
+                f[i0], f[i1] = (lax.rem(lax.add(f[i0], t), _Q),
+                                lax.rem(lax.add(lax.sub(f[i0], t), _Q), _Q))
         k += groups
         length //= 2
     return f
@@ -234,8 +241,8 @@ def ntt_inv_tiles(f: list) -> list:
             base = g * 2 * length
             for j in range(length):
                 i0, i1 = base + j, base + length + j
-                s = (f[i0] + f[i1]) % Q
-                t = _mul_zeta((f[i1] - f[i0]) % Q, zs[g])
+                s = lax.rem(lax.add(f[i0], f[i1]), _Q)
+                t = _mul_zeta(lax.rem(lax.add(lax.sub(f[i1], f[i0]), _Q), _Q), zs[g])
                 f[i0], f[i1] = s, t
         k -= groups
         length *= 2

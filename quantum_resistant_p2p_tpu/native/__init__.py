@@ -2,16 +2,19 @@
 
 Fills the role liboqs plays for the reference app (vendored .so loaded via
 ctypes, reference vendor/__init__.py:12-57 + vendor/oqs.py:122-183): a native
-CPU fast path for Keccak and ML-KEM, compiled on demand with g++ (pybind11 is
+CPU fast path for every family, compiled on demand with g++ (pybind11 is
 not available in this environment; plain extern "C" + ctypes is the binding).
 
-``load()`` returns None when no compiler/library is available — callers fall
-back to the pure-Python pyref implementations, which remain the oracles.
+The library is keyed by a hash of ``qrp_native.cpp``'s content, so a cached
+build of other source can never be loaded.  A failed build raises: the
+native core serves the cpu backend and the batch queues' fallback, and a
+silent switch to pure Python there would be orders of magnitude slower.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import logging
 import os
 import subprocess
@@ -29,54 +32,40 @@ _CACHE_DIR = Path(
 
 _lock = threading.Lock()
 _lib: ctypes.CDLL | None = None
-_tried = False
 
 
-def _build() -> Path | None:
-    _CACHE_DIR.mkdir(parents=True, exist_ok=True)
-    so = _CACHE_DIR / "libqrp_native.so"
-    if so.exists() and so.stat().st_mtime >= _SRC.stat().st_mtime:
+def _build() -> Path:
+    """Path of the library built from the current source (built if absent)."""
+    digest = hashlib.sha256(_SRC.read_bytes()).hexdigest()[:16]
+    so = _CACHE_DIR / f"libqrp_native-{digest}.so"
+    if so.exists():
         return so
+    _CACHE_DIR.mkdir(parents=True, exist_ok=True)
+    tmp = so.with_suffix(f".{os.getpid()}.tmp")
     try:
         subprocess.run(
-            ["g++", "-O3", "-shared", "-fPIC", "-o", str(so), str(_SRC)],
+            ["g++", "-O3", "-shared", "-fPIC", "-o", str(tmp), str(_SRC)],
             check=True,
             capture_output=True,
             timeout=120,
         )
-        return so
+    except subprocess.CalledProcessError as e:
+        raise RuntimeError(
+            f"native build failed: {e.stderr.decode(errors='replace')[-2000:]}"
+        ) from e
     except (OSError, subprocess.SubprocessError) as e:
-        logger.warning("native build failed (falling back to pure Python): %s", e)
-        return None
+        raise RuntimeError(f"native build failed: {e}") from e
+    tmp.replace(so)  # atomic: a concurrent loader never sees half a file
+    return so
 
 
-def load() -> ctypes.CDLL | None:
-    """Build-if-needed and load the native library; None on failure."""
-    global _lib, _tried
+def load() -> ctypes.CDLL:
+    """Build-if-needed and load the native library; raises on failure."""
+    global _lib
     with _lock:
-        if _tried:
-            return _lib
-        _tried = True
-        if not _SRC.exists():
-            return None
-        so = _build()
-        if so is None:
-            return None
-        try:
+        if _lib is None:
+            so = _build()
             _lib = _bind(ctypes.CDLL(str(so)))
-        except AttributeError:
-            # Stale cached .so predating newer symbols (e.g. synced with
-            # preserved mtimes): force one rebuild, then give up to the
-            # pure-Python fallback rather than raising out of load().
-            logger.warning("cached native library is stale; rebuilding")
-            try:
-                so.unlink()
-                so = _build()
-                _lib = _bind(ctypes.CDLL(str(so))) if so else None
-            except (OSError, AttributeError) as e:
-                logger.warning("native rebuild failed (pure-Python fallback): %s", e)
-                _lib = None
-        if _lib is not None:
             logger.info(
                 "loaded native crypto core v%d from %s", _lib.qrp_version(), so
             )
@@ -144,8 +133,6 @@ class NativeMLKEM:
 
     def __init__(self, name: str):
         self.lib = load()
-        if self.lib is None:
-            raise RuntimeError("native core unavailable")
         self.k = self._K[name]
         self.ek_len = 384 * self.k + 32
         self.dk_len = 768 * self.k + 96
@@ -178,8 +165,6 @@ class NativeMLDSA:
         from ..pyref import mldsa_ref  # single authority for sizes
 
         self.lib = load()
-        if self.lib is None:
-            raise RuntimeError("native core unavailable")
         self.level = self._LEVEL[name]
         p = mldsa_ref.PARAMS[name]
         self.pk_len, self.sk_len, self.sig_len = p.pk_len, p.sk_len, p.sig_len
@@ -231,8 +216,6 @@ class NativeSLHDSA:
         from ..pyref import slhdsa_ref  # single authority for sizes
 
         self.lib = load()
-        if self.lib is None:
-            raise RuntimeError("native core unavailable")
         self.param_id = self._ID[name]
         p = slhdsa_ref.PARAMS[name]
         self.n, self.sig_len = p.n, p.sig_len
@@ -280,8 +263,6 @@ class NativeFrodoKEM:
         from ..pyref import frodo_ref  # single authority for sizes
 
         self.lib = load()
-        if self.lib is None:
-            raise RuntimeError("native core unavailable")
         self.param_id = self._ID[name]
         p = frodo_ref.PARAMS[name]
         self.len_sec = p.len_sec
@@ -319,8 +300,6 @@ class NativeHQC:
         from ..pyref import hqc_ref  # single authority for sizes
 
         self.lib = load()
-        if self.lib is None:
-            raise RuntimeError("native core unavailable")
         self.param_id = self._ID[name]
         p = hqc_ref.PARAMS[name]
         self.k = p.k
@@ -355,8 +334,6 @@ class NativeHQC:
 
 def shake256(data: bytes, out_len: int) -> bytes:
     lib = load()
-    if lib is None:
-        raise RuntimeError("native core unavailable")
     out = _out(out_len)
     lib.qrp_shake256(_buf(data), len(data), out, out_len)
     return bytes(out)
@@ -366,10 +343,6 @@ def zeroize(buf: bytearray) -> None:
     """Best-effort secure wipe of a mutable buffer (reference analog:
     OQS_MEM_cleanse via vendor/oqs.py:383-390)."""
     lib = load()
-    if lib is None:
-        for i in range(len(buf)):
-            buf[i] = 0
-        return
     c = (ctypes.c_uint8 * len(buf)).from_buffer(buf)
     lib.qrp_zeroize(c, len(buf))
 

@@ -48,7 +48,7 @@ def shard_devices(n: int | None = None) -> list[jax.Device]:
     """The first ``n`` devices of the placement axis (default: all).
 
     The latency-path twin of :func:`make_mesh`: where the mesh shards ONE
-    big batch across chips (GSPMD), the placement axis
+    big batch across chips (shard_map), the placement axis
     (provider/scheduler.py) pins each small queue flush WHOLE onto one of
     these devices.  Raises like make_mesh when fewer devices exist."""
     devs = jax.devices()
@@ -88,12 +88,16 @@ def handshake_step(p, d, z, m):
 
 @functools.cache
 def make_sharded_handshake(mesh: Mesh, param_name: str = "ML-KEM-768"):
-    """Jit the full handshake step with batch-sharded in/out shardings."""
+    """Jit the full handshake step over the batch-sharded mesh: each chip
+    runs its shard (``shard_map``: the Pallas kernels inside cannot be
+    partitioned by the compiler), and ``n_ok`` is psum-reduced."""
     p = PARAMS[param_name]
-    data_sh = NamedSharding(mesh, P(BATCH_AXIS))
-    scalar_sh = NamedSharding(mesh, P())
-    return jax.jit(
-        functools.partial(handshake_step, p),
-        in_shardings=(data_sh,) * 3,
-        out_shardings=(data_sh,) * 4 + (scalar_sh,),
-    )
+
+    def local(d, z, m):
+        ek, ct, key_e, key_d, n_ok = handshake_step(p, d, z, m)
+        return ek, ct, key_e, key_d, jax.lax.psum(n_ok, BATCH_AXIS)
+
+    data = P(BATCH_AXIS)
+    return jax.jit(jax.shard_map(
+        local, mesh=mesh, in_specs=(data,) * 3,
+        out_specs=(data,) * 4 + (P(),), check_vma=False))

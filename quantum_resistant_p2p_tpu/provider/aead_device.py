@@ -44,12 +44,12 @@ class ChaChaPolyDevice(BatchedAEADOps):
 
     def __init__(self, use_pallas: bool | None = None,
                  interpret: bool = False):
-        from ..core import chacha_pallas
+        from ..core import chacha_pallas, keccak
 
         self._core = chacha_pallas
-        #: Pallas kernel on real TPU, jnp twin elsewhere (bit-identical;
-        #: core.keccak's shared QRP2P_PALLAS policy)
-        self.use_pallas = (chacha_pallas.use_pallas_default()
+        #: Pallas kernel on a TPU, jnp twin elsewhere (bit-identical;
+        #: core.keccak's platform gate)
+        self.use_pallas = (keccak._use_pallas()
                            if use_pallas is None else use_pallas)
         self.interpret = interpret
         #: (seal, batch, msg_bucket, aad_bucket) program shapes this

@@ -16,33 +16,19 @@ batch-of-1 special case, which is the inversion that makes 50k ops/s possible.
 from __future__ import annotations
 
 import abc
-import logging
+import functools
 from typing import Any
 
 import numpy as np
 
 
 def try_native(class_name: str, algo_name: str):
-    """Instantiate a native-core wrapper (NativeMLKEM/NativeMLDSA/...), or
-    None with a logged warning when the C++ fast path is unavailable —
-    callers fall back to the pure-Python pyref implementations."""
-    try:
-        from .. import native as _native
+    """Instantiate a native-core wrapper (NativeMLKEM/NativeMLDSA/...).  A
+    failed native build raises (native/__init__.py): the cpu backend is the
+    batch queues' fallback, and pure Python there would be far too slow."""
+    from .. import native as _native
 
-        return getattr(_native, class_name)(algo_name)
-    except Exception as e:
-        logging.getLogger(__name__).warning(
-            "%s: native fast path unavailable, using pure-Python fallback "
-            "(orders of magnitude slower): %s",
-            algo_name,
-            e,
-        )
-        return None
-
-
-def cpu_impl_desc(native_obj) -> str:
-    """Truthful description of which cpu implementation actually runs."""
-    return "native C++ CPU" if native_obj is not None else "pure-Python CPU"
+    return getattr(_native, class_name)(algo_name)
 
 
 from ..utils import next_pow2  # noqa: E402  (canonical shared helper)
@@ -62,14 +48,30 @@ def pad_rows(rows: np.ndarray, target: int) -> np.ndarray:
     return np.concatenate([np.asarray(rows), pad], axis=0)
 
 
+@functools.lru_cache(maxsize=None)
+def sharded_program(fn, mesh):
+    """``fn`` run by every device of ``mesh`` on its own shard of the batch
+    axis (``shard_map``), jitted once per (fn, mesh).
+
+    Not GSPMD: the compiler cannot partition a Mosaic (Pallas) kernel, and
+    every device program of the served path holds some.  Crypto batches are
+    embarrassingly parallel, so each device runs the unchanged per-row
+    program on its rows with no collective."""
+    import jax
+    from jax.sharding import PartitionSpec
+
+    spec = PartitionSpec(mesh.axis_names[0])
+    return jax.jit(jax.shard_map(fn, mesh=mesh, in_specs=spec,
+                                 out_specs=spec, check_vma=False))
+
+
 def mesh_dispatch(fn, mesh, *arrays):
     """Run a jitted batch fn with the batch axis sharded across ``mesh``.
 
     TPU-native scale-out for embarrassingly parallel crypto batches
     (SURVEY.md §2.3): operands are placed with a batch-axis NamedSharding and
-    the computation follows the data — GSPMD partitions the already-jitted
-    program across the mesh with zero cross-chip collectives on the hot path
-    (each chip runs its shard of keygen/encaps/decaps/sign/verify locally).
+    each chip runs its shard of keygen/encaps/decaps/sign/verify locally
+    (:func:`sharded_program`), with zero cross-chip collectives.
 
     The batch is padded (last row repeated) to ``n_devices * pow2`` so every
     device receives an equal, compile-cached shard; results gather on the
@@ -89,7 +91,7 @@ def mesh_dispatch(fn, mesh, *arrays):
     tgt = ndev * next_pow2(-(-n // ndev))
     sh = NamedSharding(mesh, PartitionSpec(mesh.axis_names[0]))
     parts = [jax.device_put(pad_rows(np.asarray(a), tgt), sh) for a in arrays]
-    out = fn(*parts)
+    out = sharded_program(fn, mesh)(*parts)
     if isinstance(out, tuple):
         return tuple(np.asarray(o)[:n] for o in out)
     return np.asarray(out)[:n]
@@ -98,10 +100,9 @@ def mesh_dispatch(fn, mesh, *arrays):
 def sliced_dispatch(fn, step: int, *arrays, mesh=None):
     """Run a jitted batch fn in ``step``-row slices and concatenate.
 
-    Two reasons to slice device batches: FrodoKEM dispatches >= 1024 crash
-    this environment's TPU worker (kem/frodo.py), and ML-KEM throughput peaks
-    well below the queue's max batch (working set vs HBM/caches — see
-    bench_report.md's scaling curve).  A non-divisible tail is padded to a
+    Each family caps its dispatch at ``MAX_DEVICE_BATCH`` rows (kem/mlkem.py,
+    kem/frodo.py, kem/hqc.py): the caps keep a flush's working set bounded
+    and await a sweep on the chip.  A non-divisible tail is padded to a
     full slice (last row repeated) so every dispatch hits an already-compiled
     shape, then trimmed.
 

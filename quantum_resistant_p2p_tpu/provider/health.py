@@ -8,8 +8,8 @@ the documented in-repo example, kem/hqc.py).  This module runs fast
 on-device self-checks at provider startup:
 
 * **HQC** — the FFT-vs-Toeplitz cyclic-product exactness probe
-  (``kem.hqc._fft_selfcheck``, the same check ``tools/check_pallas_device``
-  runs manually); an unvalidated environment routes HQC to the exact
+  (``kem.hqc._fft_selfcheck``, the same check ``chip_smoke.py`` runs);
+  an unvalidated environment routes HQC to the exact
   Toeplitz-MXU path and logs why.
 * **ML-KEM** — a pinned known-answer vector: deterministic
   ``keygen(d, z)`` / ``encaps(ek, m)`` digests computed from the pure-Python
@@ -20,12 +20,15 @@ on-device self-checks at provider startup:
   device signatures must verify on the cpu backend and a tampered signature
   must not).
 
+Each probe runs at its facade's bucket floor (``rows``), so it exercises the
+very programs the queue's flushes run and compiles nothing of its own; a
+cold start with a large floor would otherwise compile every program twice.
+
 Verdicts are keyed by an environment fingerprint (device kind, platform,
 jax/jaxlib versions) and cached on disk (the native-build cache dir), so the
 cost is once per environment, not per process.  Only POSITIVE verdicts are
-trusted from the cache — this platform's device faults are documented
-transient, so a failed probe re-runs at next startup (self-healing) instead
-of pinning the slow path forever.
+trusted from the cache: a failed probe re-runs at next startup
+(self-healing) instead of pinning the slow path forever.
 
 On failure the gate acts, loudly: HQC is re-routed to the Toeplitz path, and
 a batched facade whose device provider fails is QUARANTINED — its shared
@@ -152,7 +155,7 @@ def _write_cached(family: str, fingerprint: str, verdict: HealthVerdict) -> None
 
 def _check_hqc(algo) -> HealthVerdict:
     """FFT-vs-Toeplitz cyclic-product exactness on-device (the check
-    ``tools/check_pallas_device.py`` runs manually).  An unvalidated
+    ``chip_smoke.py`` runs).  An unvalidated
     environment is HEALED, not quarantined: ``kem.hqc`` re-routes every HQC
     op to the exact Toeplitz-MXU product for this process and logs why —
     so the verdict is ok either way, with the routing in the detail.
@@ -172,17 +175,24 @@ def _check_hqc(algo) -> HealthVerdict:
     return HealthVerdict(algo.name, True, detail, cacheable=False)
 
 
-def _check_mlkem_kat(algo) -> HealthVerdict:
-    """Pinned FIPS 203 vector through the device (jax) path, batch-1."""
+def _rows(data: bytes, rows: int):
+    """One PUBLIC byte string as ``rows`` identical uint8 rows (a probe's
+    batch); secret rows are built inline, where their wipe is."""
+    import numpy as np
+
+    return np.repeat(np.frombuffer(data, np.uint8)[None], rows, axis=0)
+
+
+def _check_mlkem_kat(algo, rows: int = 1) -> HealthVerdict:
+    """Pinned FIPS 203 vector through the device (jax) path, in a batch of
+    ``rows`` identical rows."""
     import numpy as np
 
     from ..kem import mlkem
 
     kat = _MLKEM768_KAT
     kg, enc, dec = mlkem.get("ML-KEM-768")
-    d = np.frombuffer(kat["d"], np.uint8)[None]
-    z = np.frombuffer(kat["z"], np.uint8)[None]
-    m = np.frombuffer(kat["m"], np.uint8)[None]
+    d, z, m = (_rows(kat[k], rows) for k in ("d", "z", "m"))
     ek, dk = kg(d, z)
     ek_b = bytes(np.asarray(ek[0], np.uint8))
     if hashlib.sha256(ek_b).hexdigest() != kat["ek_sha256"]:
@@ -236,13 +246,15 @@ def _check_frodo_kat(algo) -> HealthVerdict:
                          "FrodoKEM KAT ok (keygen/encaps/decaps, pyref-pinned)")
 
 
-def _check_kem_roundtrip(algo, cpu_twin) -> HealthVerdict:
-    """Device roundtrip + cross-implementation agreement with the cpu twin."""
-    pk, sk = algo.generate_keypair()
-    ss = b""
+def _check_kem_roundtrip(algo, cpu_twin, rows: int = 1) -> HealthVerdict:
+    """Device roundtrip + cross-implementation agreement with the cpu twin,
+    on row 0 of a ``rows``-row batch."""
+    pks, sks = algo.generate_keypair_batch(rows)
+    cts, sss = algo.encapsulate_batch(pks)
+    got = algo.decapsulate_batch(sks, cts)
+    sk, ct, ss = (bytes(x[0]) for x in (sks, cts, sss))
     try:
-        ct, ss = algo.encapsulate(pk)
-        if not hmac.compare_digest(algo.decapsulate(sk, ct), ss):
+        if not hmac.compare_digest(bytes(got[0]), ss):
             return HealthVerdict(algo.name, False,
                                  "device decaps != device encaps")
         if cpu_twin is not None and not hmac.compare_digest(
@@ -254,16 +266,21 @@ def _check_kem_roundtrip(algo, cpu_twin) -> HealthVerdict:
         agree = " + cpu agreement" if cpu_twin is not None else ""
         return HealthVerdict(algo.name, True, f"device roundtrip ok{agree}")
     finally:
-        wipe(sk, ss)  # probe-only key material
+        wipe(sks, sss, got, sk, ss)  # probe-only key material
 
 
-def _check_sig_roundtrip(algo, cpu_twin) -> HealthVerdict:
-    """Device sign/verify + cross-implementation verify + tamper rejection."""
+def _check_sig_roundtrip(algo, cpu_twin, rows: int = 1) -> HealthVerdict:
+    """Device sign/verify + cross-implementation verify + tamper rejection,
+    in batches of ``rows`` rows under one key."""
+    import numpy as np
+
     msg = b"qrp2p device-health probe"
     pk, sk = algo.generate_keypair()
+    pks = _rows(pk, rows)
+    sks = np.repeat(np.frombuffer(sk, np.uint8)[None], rows, axis=0)
     try:
-        sig = algo.sign(sk, msg)
-        if not algo.verify(pk, msg, sig):
+        sig = bytes(algo.sign_batch(sks, [msg] * rows)[0])
+        if not algo.verify_batch(pks, [msg] * rows, [sig] * rows)[0]:
             return HealthVerdict(algo.name, False,
                                  "device verify rejects device sign")
         if cpu_twin is not None and not cpu_twin.verify(pk, msg, sig):
@@ -272,22 +289,22 @@ def _check_sig_roundtrip(algo, cpu_twin) -> HealthVerdict:
                 "cpu reference verify rejects device signature",
             )
         bad = bytes([sig[0] ^ 0xFF]) + sig[1:]
-        if algo.verify(pk, msg, bad):
+        if algo.verify_batch(pks, [msg] * rows, [bad] * rows)[0]:
             return HealthVerdict(algo.name, False,
                                  "device verify accepts tampered sig")
         agree = " + cpu agreement" if cpu_twin is not None else ""
         return HealthVerdict(algo.name, True, f"device sign/verify ok{agree}")
     finally:
-        wipe(sk)  # probe-only key material
+        wipe(sk, sks)  # probe-only key material
 
 
-def _check_fused(facade) -> HealthVerdict:
+def _check_fused(facade, rows: int = 1) -> HealthVerdict:
     """Validate the composite fused-handshake path (provider/batched.py
     ``BatchedFused``): the fused programs are a SEPARATE device code path
     from the per-op families (device-side hex render into transcript
     templates + fused sign), so both can pass while these kernels are
-    broken.  Probe: one batch-1 ``keygen_sign`` at the facade's LIVE
-    offsets; the rendered-template signature must verify on the cpu twin
+    broken.  Probe: one ``keygen_sign`` of ``rows`` rows at the facade's
+    LIVE offsets; the rendered-template signature must verify on the cpu twin
     and the generated KEM keypair must roundtrip through the cpu twin —
     covering the shared render/sign machinery the other two composite ops
     reuse."""
@@ -299,13 +316,14 @@ def _check_fused(facade) -> HealthVerdict:
     if cpu_kem is None or cpu_sig is None:
         return HealthVerdict(name, True, "no cpu twins armed; skipped")
     sig_pk, sig_sk = cpu_sig.generate_keypair()
-    ss = b""
+    sig_sks = np.repeat(np.frombuffer(sig_sk, np.uint8)[None], rows, axis=0)
+    ss = ksks = b""
     try:
         tmpl_len = min(fused.init_template_len,
                        facade.pk_off + 2 * fused.kem.public_key_len + 2)
         tmpl = b"{" + b"0" * (tmpl_len - 2) + b"}"
         pks, ksks, sigs = fused.keygen_sign_batch(
-            np.frombuffer(sig_sk, np.uint8)[None], [tmpl], facade.pk_off
+            sig_sks, [tmpl] * rows, facade.pk_off
         )
         pk, ksk = (bytes(np.asarray(pks[0], np.uint8)),
                    bytes(np.asarray(ksks[0], np.uint8)))
@@ -325,7 +343,7 @@ def _check_fused(facade) -> HealthVerdict:
         return HealthVerdict(name, True,
                              "fused keygen_sign render/sign/keypair ok vs cpu")
     finally:
-        wipe(sig_sk, ss)  # probe-only key material
+        wipe(sig_sk, sig_sks, ksks, ss)  # probe-only key material
 
 
 #: pinned RFC 8439 §2.8.2 AEAD vector: the device seal must reproduce the
@@ -374,7 +392,7 @@ def _check_aead(facade) -> HealthVerdict:
     return HealthVerdict(name, True, f"RFC 8439 KAT + tamper-reject ok{agree}")
 
 
-def _probe(algo, cpu_twin) -> HealthVerdict:
+def _probe(algo, cpu_twin, rows: int = 1) -> HealthVerdict:
     name = getattr(algo, "name", type(algo).__name__)
     if name.startswith("HQC"):
         return _check_hqc(algo)
@@ -383,14 +401,14 @@ def _probe(algo, cpu_twin) -> HealthVerdict:
     if name == "ML-KEM-768":
         # the pinned vector covers keygen/encaps/decaps end to end; the
         # generic roundtrip would add nothing
-        return _check_mlkem_kat(algo)
+        return _check_mlkem_kat(algo, rows)
     if name.startswith("FrodoKEM") and name.endswith("SHAKE"):
         # certifies the shared Pallas matmul + inline-SHAKE kernel family
         return _check_frodo_kat(algo)
     if isinstance(algo, KeyExchangeAlgorithm):
-        return _check_kem_roundtrip(algo, cpu_twin)
+        return _check_kem_roundtrip(algo, cpu_twin, rows)
     if isinstance(algo, SignatureAlgorithm):
-        return _check_sig_roundtrip(algo, cpu_twin)
+        return _check_sig_roundtrip(algo, cpu_twin, rows)
     return HealthVerdict(name, True, "no probe registered; skipped")
 
 
@@ -401,7 +419,7 @@ def gate_enabled() -> bool:
     return os.environ.get("QRP2P_HEALTH_GATE", "1") != "0"
 
 
-def ensure_validated(algo, cpu_twin=None) -> HealthVerdict:
+def ensure_validated(algo, cpu_twin=None, rows: int = 1) -> HealthVerdict:
     """Run (or recall) the health probe for one provider's family.
 
     Positive verdicts are cached on disk keyed by the environment
@@ -417,7 +435,7 @@ def ensure_validated(algo, cpu_twin=None) -> HealthVerdict:
     if cached is not None:
         return cached
     try:
-        verdict = _probe(algo, cpu_twin)
+        verdict = _probe(algo, cpu_twin, rows)
     except Exception as e:
         logger.exception("device-health probe for %s crashed", family)
         verdict = HealthVerdict(family, False, f"probe crashed: {e!r}")
@@ -440,13 +458,14 @@ def gate_facades(*facades) -> list[HealthVerdict]:
     for facade in facades:
         if facade is None:
             continue
+        rows = getattr(facade, "bucket_floor", 1)
         if hasattr(facade, "fused"):
-            verdict = _ensure_fused_validated(facade)
+            verdict = _ensure_fused_validated(facade, rows)
         elif hasattr(facade, "device"):  # BatchedAEAD (data plane)
             verdict = _ensure_aead_validated(facade)
         else:
             verdict = ensure_validated(facade.algo,
-                                       getattr(facade, "fallback", None))
+                                       getattr(facade, "fallback", None), rows)
         out.append(verdict)
         from ..obs import flight as _flight
 
@@ -499,7 +518,7 @@ def _ensure_aead_validated(facade) -> HealthVerdict:
     return verdict
 
 
-def _ensure_fused_validated(facade) -> HealthVerdict:
+def _ensure_fused_validated(facade, rows: int = 1) -> HealthVerdict:
     """Cached wrapper around :func:`_check_fused` (same verdict policy as
     ensure_validated; the cache key carries the live transcript offsets —
     jit keys on them, so a different protocol layout re-probes)."""
@@ -509,7 +528,7 @@ def _ensure_fused_validated(facade) -> HealthVerdict:
     if cached is not None:
         return cached
     try:
-        verdict = _check_fused(facade)
+        verdict = _check_fused(facade, rows)
     except Exception as e:
         logger.exception("device-health probe for %s crashed", family)
         verdict = HealthVerdict(family, False, f"probe crashed: {e!r}")

@@ -17,7 +17,7 @@ Handshake crypto is embarrassingly parallel, so the two production paths
 split cleanly (docs/sharding.md):
 
 * **Large-batch raw-ops path** — a single big batch is partitioned ACROSS
-  the mesh via ``jax.sharding``/GSPMD (``provider.base.mesh_dispatch``,
+  the mesh via ``shard_map`` (``provider.base.mesh_dispatch``,
   the ``devices=`` knob on providers).  One program, N chips, zero
   hot-path collectives.
 * **Latency-sensitive handshake path** — many small queue flushes are
@@ -36,24 +36,20 @@ cool-off expires, running the PR-3 heal cycle per shard.
 
 Degradation: ``shards=1`` (the default everywhere) is a single logical
 shard with no device pinned — bit-for-bit the pre-scheduler behavior,
-pinned by metrics-parity tests.  When jax (or enough devices) is absent,
-requested shards degrade to LOGICAL shards: per-shard breakers, queues
-and placement still partition the work (and are fully testable), only the
-physical device pinning is skipped.
+pinned by metrics-parity tests.  A request for more physical shards than
+there are devices raises; tests that want logical slots pass
+``devices=[None, ...]`` explicitly.
 """
 
 from __future__ import annotations
 
 import contextlib
-import logging
 import threading
 import time
 from typing import Any, Callable
 
 from ..obs import flight as obs_flight
 from .batched import Breaker, CoalescingHub
-
-logger = logging.getLogger(__name__)
 
 
 def select_slot(slots):
@@ -90,22 +86,12 @@ def select_slot(slots):
 
 
 def _resolve_devices(n: int) -> list[Any]:
-    """First ``n`` visible accelerator devices (n == -1: all), or logical
-    placeholders (``None``) when jax or the devices are unavailable —
-    placement, per-shard breakers and quarantine still work, only the
-    physical device pinning is skipped."""
-    try:
-        from ..parallel.mesh import shard_devices
+    """First ``n`` visible devices (n == -1: all).  A physical shard count
+    that is not there raises: placing flushes on logical slots would serve
+    a request for N chips from one."""
+    from ..parallel.mesh import shard_devices
 
-        devs = shard_devices(None if n < 0 else n)
-    except Exception as e:  # qrlint: disable=broad-except  — missing jax / too few devices must degrade to logical shards, not fail construction on minimal images
-        count = 1 if n < 0 else n
-        logger.warning(
-            "shard placement: %d physical device(s) unavailable (%s); "
-            "using logical shards", count, e,
-        )
-        return [None] * count
-    return list(devs)
+    return shard_devices(None if n < 0 else n)
 
 
 class Shard:

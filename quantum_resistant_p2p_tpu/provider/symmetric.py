@@ -1,16 +1,11 @@
 """AEAD algorithms: AES-256-GCM and ChaCha20-Poly1305.
 
 Scalar host-side path (OpenSSL via the ``cryptography`` package), as in the
-reference (crypto/symmetric.py:66-258).  Two additions over the reference:
+reference (crypto/symmetric.py:66-258).  One addition over the reference:
 
-* deterministic-nonce ``seal``/``open_`` primitives (``encrypt`` is
-  ``urandom nonce + seal``) — the batched device AEAD's cpu fallback and
-  its cross-check tests need the nonce as an explicit operand;
-* a wheel-less pure-Python fallback for ChaCha20-Poly1305
-  (pyref/chacha_ref.py): minimal accelerator images without OpenSSL can
-  still run the full bulk path — slowly, which is exactly what the batched
-  device path (core/chacha_pallas.py, ``BatchedAEADOps``) exists to fix.
-  AES-256-GCM has no pure-Python twin and still requires the wheel.
+deterministic-nonce ``seal``/``open_`` primitives (``encrypt`` is
+``urandom nonce + seal``): the batched device AEAD's cpu fallback and its
+cross-check tests need the nonce as an explicit operand.
 
 Wire format parity: 12-byte random nonce prepended to the ciphertext
 (crypto/symmetric.py:110-146); authentication failure raises ValueError
@@ -21,25 +16,14 @@ from __future__ import annotations
 
 import os
 
-try:
-    from cryptography.exceptions import InvalidTag
-    from cryptography.hazmat.primitives.ciphers import aead as _aead
-except ImportError:  # pragma: no cover - exercised only on minimal images
-    # Gate, don't crash: the provider package (registry, batch queues, KEM/
-    # signature providers) is fully usable without host AEAD — only actual
-    # encrypt/decrypt needs OpenSSL.  Minimal accelerator images without
-    # the wheel can still run the PQC layers and their tests (and, via the
-    # pyref fallback below, the ChaCha20-Poly1305 bulk path).
-    class InvalidTag(Exception):  # placeholder: never raised without OpenSSL
-        pass
-
-    _aead = None
+from cryptography.exceptions import InvalidTag
+from cryptography.hazmat.primitives.ciphers import aead as _aead
 
 from .base import SymmetricAlgorithm
 
 
 class _AEADBase(SymmetricAlgorithm):
-    _impl = ""  # cryptography AEAD class name (resolved lazily by _cipher)
+    _impl = ""  # cryptography AEAD class name
 
     key_size = 32
     nonce_size = 12
@@ -50,10 +34,6 @@ class _AEADBase(SymmetricAlgorithm):
 
     @property
     def _cipher(self):
-        if _aead is None:
-            raise RuntimeError(
-                f"{self.name} needs the 'cryptography' package for host AEAD"
-            )
         return getattr(_aead, self._impl)
 
     def _check_key(self, key: bytes) -> None:
@@ -108,25 +88,3 @@ class ChaCha20Poly1305(_AEADBase):
     description = "RFC 8439 ChaCha20-Poly1305 AEAD"
     security_level = 5
     backend = "cpu"
-
-    def seal(self, key: bytes, nonce: bytes, plaintext: bytes,
-             associated_data: bytes | None = None) -> bytes:
-        if _aead is not None:
-            return super().seal(key, nonce, plaintext, associated_data)
-        # wheel-less scalar twin (pyref/chacha_ref.py): bit-identical to
-        # OpenSSL, pure stdlib — the KAT oracle doubles as the fallback
-        from ..pyref import chacha_ref
-
-        self._check_key(key)
-        return chacha_ref.seal(bytes(key), bytes(nonce), bytes(plaintext),
-                               bytes(associated_data or b""))
-
-    def open_(self, key: bytes, nonce: bytes, data: bytes,
-              associated_data: bytes | None = None) -> bytes:
-        if _aead is not None:
-            return super().open_(key, nonce, data, associated_data)
-        from ..pyref import chacha_ref
-
-        self._check_key(key)
-        return chacha_ref.open_(bytes(key), bytes(nonce), bytes(data),
-                                bytes(associated_data or b""))

@@ -1,15 +1,9 @@
 """Pure-Python RFC 8439 ChaCha20-Poly1305 — the scalar reference twin.
 
-Two jobs, mirroring the other pyref modules:
-
-* the KAT oracle for the batched device AEAD (core/chacha_pallas.py): the
-  device seal/open must be bit-exact against this implementation at every
-  length bucket, masked tail, and AAD shape (tests/test_chacha_pallas.py
-  pins the RFC 8439 §2.8.2 vector through BOTH paths);
-* the wheel-less scalar fallback: ``provider/symmetric.py`` routes
-  ChaCha20-Poly1305 here when the OpenSSL ``cryptography`` wheel is absent
-  (minimal accelerator images), so the protocol engine's bulk path — and
-  the batched queue's cpu fallback — works everywhere the PQC layers do.
+The KAT oracle for the batched device AEAD (core/chacha_pallas.py): the
+device seal/open must be bit-exact against this implementation at every
+length bucket, masked tail, and AAD shape (tests/test_chacha_pallas.py
+pins the RFC 8439 §2.8.2 vector through BOTH paths).
 
 Spec: RFC 8439 (ChaCha20 §2.3, Poly1305 §2.5, AEAD construction §2.8).
 Performance is NOT a goal here — the whole point of the device path is

@@ -21,13 +21,7 @@ from __future__ import annotations
 import hashlib
 from dataclasses import dataclass
 
-try:
-    from cryptography.hazmat.primitives.ciphers import Cipher, algorithms, modes
-except ImportError:  # pragma: no cover - exercised only on minimal images
-    # Gate, don't crash: only FrodoKEM's AES matrix expansion needs it;
-    # importers using the SHAKE variant (or other pyref modules) work
-    # without the wheel.
-    Cipher = algorithms = modes = None
+from cryptography.hazmat.primitives.ciphers import Cipher, algorithms, modes
 
 NBAR = 8
 
@@ -102,10 +96,6 @@ def gen_a(p: FrodoParams, seed_a: bytes) -> list[list[int]]:
     mask = p.q - 1
     a = []
     if p.aes:
-        if Cipher is None:
-            raise RuntimeError(
-                "FrodoKEM-AES matrix expansion needs the 'cryptography' package"
-            )
         enc = Cipher(algorithms.AES(seed_a), modes.ECB()).encryptor()
         for i in range(n):
             row = []

@@ -597,12 +597,13 @@ def sign_mu_rounds(p: MLDSAParams, sk: jax.Array, mu: jax.Array, rnd: jax.Array,
     selection keeps each lane's FIRST accept, so results are bit-identical;
     ``n_iters`` must be a multiple of ``unroll`` so the attempt budget —
     and thus the returned (done, kappa) resumption state — is exactly the
-    unroll=1 contract).  Committed NEGATIVE result (bench_report.md):
-    an in-loop attempt measures ~155 ms at batch 8192 while its standalone
-    stages sum to ~55 ms, but unroll=5 changed nothing (784.7 vs 794.6 ms
-    for 5 attempts) — the gap is NOT the iteration boundary; standalone
-    stage timings are flattered by cross-dispatch overlap in the timing
-    harness, and the serial in-context chain is the true cost.  Default 1.
+    unroll=1 contract).  A negative result from an earlier platform, not
+    measured on this chip: an in-loop attempt took ~155 ms at batch 8192
+    while its standalone stages summed to ~55 ms, but unroll=5 changed
+    nothing (784.7 vs 794.6 ms for 5 attempts) — the gap was not the
+    iteration boundary; standalone stage timings are flattered by
+    cross-dispatch overlap, and the serial in-context chain is the true
+    cost.  Default 1.
     """
     return _sign_mu_core(p, precompute_sk(p, sk), mu, rnd, kappa0, n_iters,
                          unroll)
@@ -707,10 +708,10 @@ def sign_mu_pre(p: MLDSAParams, pre: dict[str, jax.Array], mu: jax.Array,
 
 #: compact-and-refill schedule: iterations for the first dispatches; after
 #: the schedule is exhausted the surviving (small) bucket runs to
-#: completion in ONE dispatch.  Three total dispatches — on a remote/slow
-#: link each round-trip costs real time, so the tail must not become a
-#: string of tiny rounds (measured: a 3-iter/round greedy schedule was 2x
-#: SLOWER than the plain loop from ~11 rounds of dispatch overhead).
+#: completion in ONE dispatch.  Three total dispatches — each round trip
+#: costs real time, so the tail must not become a string of tiny rounds
+#: (an earlier platform measured a 3-iter/round greedy schedule 2x SLOWER
+#: than the plain loop from ~11 rounds of dispatch overhead).
 COMPACT_SCHEDULE = (6, 6)
 
 

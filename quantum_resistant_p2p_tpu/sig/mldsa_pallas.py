@@ -26,6 +26,8 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+from jax import lax
 
 from ..core.keccak_pallas import _f1600, absorb_block, block_bytes, sampler_call
 from ..core.sortnet import bitonic_sort_pairs_regs, bitonic_sort_regs
@@ -159,6 +161,9 @@ from ..pyref.mldsa_ref import ZETAS as _ZETAS_PY
 
 _N = 256
 _N_INV = pow(_N, -1, Q)
+# the butterflies in lax primitives, not jnp operators, for trace time (see
+# core/keccak_pallas.py); the jaxpr is the same
+_Q = np.int32(Q)
 
 
 def _mm_zeta(a, z: int):
@@ -171,9 +176,11 @@ def _mm_zeta(a, z: int):
     # qrkernel: assume a in [0, Q) — FIPS 204 §7.5: NTT butterfly operands are mod-q residues (every caller reduces % Q first)
     # qrkernel: assume z in [0, Q) — zeta table entries are powers of the 512th root of unity mod q
     b2, b1, b0 = z >> 16, (z >> 8) & 0xFF, z & 0xFF
-    r = (a * b2) % Q
-    r = (((r << 8) % Q) + (a * b1) % Q) % Q
-    r = (((r << 8) % Q) + (a * b0) % Q) % Q
+    r = lax.rem(lax.mul(a, np.int32(b2)), _Q)
+    r = lax.rem(lax.add(lax.rem(lax.shift_left(r, np.int32(8)), _Q),
+                        lax.rem(lax.mul(a, np.int32(b1)), _Q)), _Q)
+    r = lax.rem(lax.add(lax.rem(lax.shift_left(r, np.int32(8)), _Q),
+                        lax.rem(lax.mul(a, np.int32(b0)), _Q)), _Q)
     return r
 
 
@@ -190,7 +197,8 @@ def ntt_tiles(f: list) -> list:
             for j in range(length):
                 i0, i1 = base + j, base + length + j
                 t = _mm_zeta(f[i1], z)
-                f[i0], f[i1] = (f[i0] + t) % Q, (f[i0] - t) % Q
+                f[i0], f[i1] = (lax.rem(lax.add(f[i0], t), _Q),
+                                lax.rem(lax.add(lax.sub(f[i0], t), _Q), _Q))
         k += groups
         length //= 2
     return f
@@ -208,8 +216,8 @@ def ntt_inv_tiles(f: list) -> list:
             base = g * 2 * length
             for j in range(length):
                 i0, i1 = base + j, base + length + j
-                s = (f[i0] + f[i1]) % Q
-                t = _mm_zeta((f[i1] - f[i0]) % Q, zs[g])
+                s = lax.rem(lax.add(f[i0], f[i1]), _Q)
+                t = _mm_zeta(lax.rem(lax.add(lax.sub(f[i1], f[i0]), _Q), _Q), zs[g])
                 f[i0], f[i1] = s, t
         k -= groups
         length *= 2

@@ -1,8 +1,8 @@
 """Batched ChaCha20-Poly1305 KATs: RFC 8439 vectors + scalar-twin parity.
 
 The device data plane (core/chacha_pallas.py) must be bit-exact against
-the pure-Python scalar twin (pyref/chacha_ref.py) — and, when the OpenSSL
-wheel is present, against the ``cryptography`` package — at EVERY length
+the pure-Python scalar twin (pyref/chacha_ref.py) — and against the
+``cryptography`` package — at EVERY length
 bucket, masked-tail edge (15/16/17-byte plaintexts), and AAD shape
 (including empty AAD).  Fast tier runs the jnp twin; the Pallas kernel's
 interpret-mode equality is slow-tier (interpret mode simulates every
@@ -11,7 +11,6 @@ vector op, like the keccak kernel tests).
 
 from __future__ import annotations
 
-import importlib.util
 
 import numpy as np
 import pytest
@@ -160,11 +159,9 @@ def test_per_bucket_sizes_are_bit_exact():
                 == ref.seal(key, nonce, pt, b"bucket-aad"))
 
 
-# -- cross-check vs the OpenSSL wheel (skipped wheel-less) --------------------
+# -- cross-check vs the OpenSSL wheel -----------------------------------------
 
 
-@pytest.mark.skipif(importlib.util.find_spec("cryptography") is None,
-                    reason="cryptography wheel not installed")
 def test_device_core_matches_cryptography_wheel():
     from cryptography.hazmat.primitives.ciphers.aead import (
         ChaCha20Poly1305 as WheelChaCha)

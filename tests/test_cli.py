@@ -5,7 +5,6 @@ import io
 
 import pytest
 
-pytest.importorskip("cryptography")  # the CLI unlocks the AES-GCM vault
 
 from quantum_resistant_p2p_tpu.cli import CLI
 

@@ -548,3 +548,40 @@ def test_roll_storm_sessions_survive_and_resume(run):
     assert out["resumed_reconnects"] >= 1
     assert out["full_handshake_reconnects"] == 0
     assert out["post_roll_resume_rate"] in (None, 1.0)
+
+
+# -- one chip per process -----------------------------------------------------
+
+
+def test_process_spawn_refused_while_this_process_holds_a_chip(monkeypatch):
+    """A gateway subprocess on the real providers cannot reach a chip its
+    parent holds; the fleet refuses to spawn one instead of letting it fail
+    or hang.  Stdlib-provider gateways never touch the device."""
+    import jax
+
+    from quantum_resistant_p2p_tpu.fleet import gateway
+
+    jax.devices()  # the suite's CPU backend: nothing is held
+    gateway.refuse_if_chip_held("device")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(RuntimeError, match="spawn='task'"):
+        gateway.refuse_if_chip_held("device")
+    gateway.refuse_if_chip_held("stdlib")
+
+
+def test_process_fleet_serves_on_a_cpu_host_without_jax_platforms(
+        run, monkeypatch):
+    """A process fleet on a host with no accelerator and JAX_PLATFORMS
+    unset comes up: nothing on the spawn path insists on a chip."""
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+
+    async def scenario():
+        fleet = GatewayFleet(1, spawn="process", **FAST)
+        await fleet.start()
+        try:
+            member = fleet.members["gw0"]
+            assert member.registered and member.proc.returncode is None
+        finally:
+            await fleet.stop()
+
+    run(scenario())

@@ -9,7 +9,6 @@ RNG = np.random.default_rng(640)
 
 
 def test_aes_kernel_matches_cryptography():
-    pytest.importorskip("cryptography")
     from cryptography.hazmat.primitives.ciphers import Cipher, algorithms, modes
 
     from quantum_resistant_p2p_tpu.core import aes as jaes
@@ -26,8 +25,6 @@ def test_aes_kernel_matches_cryptography():
 
 @pytest.mark.parametrize("name", ["FrodoKEM-640-AES", "FrodoKEM-640-SHAKE"])
 def test_matches_oracle(name):
-    if "AES" in name:
-        pytest.importorskip("cryptography")  # pyref oracle's matrix expansion
     from quantum_resistant_p2p_tpu.kem import frodo as jfr
 
     p = fr.PARAMS[name]
@@ -89,7 +86,6 @@ def test_bitsliced_aes_matches_gather_and_openssl():
     """The table-free bitsliced AES (core/aes_bitsliced.py) is bit-exact vs
     both the gather implementation and the OpenSSL oracle, including a
     non-multiple-of-32 block count (packing pad path)."""
-    pytest.importorskip("cryptography")
     from cryptography.hazmat.primitives.ciphers import Cipher, algorithms, modes
 
     from quantum_resistant_p2p_tpu.core import aes, aes_bitsliced

@@ -37,7 +37,6 @@ from quantum_resistant_p2p_tpu.pyref import (
 
 VECTOR_DIR = Path(__file__).parent / "vectors"
 
-_HAVE_NATIVE = native.load() is not None
 
 
 def _load(fname: str) -> dict:
@@ -67,7 +66,6 @@ def _b(rec: dict, key: str) -> bytes:
 
 
 def test_ctr_drbg_known_answer():
-    pytest.importorskip("cryptography")  # the DRBG is AES-256-CTR
     from quantum_resistant_p2p_tpu.utils.ctr_drbg import CtrDrbg
 
     drbg = CtrDrbg(bytes(range(48)))
@@ -88,7 +86,7 @@ MLKEM_FILES = ["mlkem_512.json", "mlkem_768.json", "mlkem_1024.json"]
 def test_mlkem_kat_pyref_and_native(fname):
     data = _load(fname)
     p = mlkem_ref.PARAMS[data["algorithm"]]
-    nat = native.NativeMLKEM(data["algorithm"]) if _HAVE_NATIVE else None
+    nat = native.NativeMLKEM(data["algorithm"])
     for rec in data["tests"]:
         d, z, m = _b(rec, "d"), _b(rec, "z"), _b(rec, "m")
         ek, dk = mlkem_ref.keygen(p, d, z)
@@ -100,13 +98,12 @@ def test_mlkem_kat_pyref_and_native(fname):
         assert mlkem_ref.decaps(p, dk, ct) == key
         bad = bytes([ct[0] ^ 1]) + ct[1:]
         _check(rec, "ss_reject", mlkem_ref.decaps(p, dk, bad))
-        if nat is not None:
-            nek, ndk = nat.keygen(d, z)
-            assert (nek, ndk) == (ek, dk)
-            nkey, nct = nat.encaps(ek, m)
-            assert (nkey, nct) == (key, ct)
-            assert nat.decaps(dk, ct) == key
-            assert nat.decaps(dk, bad) == mlkem_ref.decaps(p, dk, bad)
+        nek, ndk = nat.keygen(d, z)
+        assert (nek, ndk) == (ek, dk)
+        nkey, nct = nat.encaps(ek, m)
+        assert (nkey, nct) == (key, ct)
+        assert nat.decaps(dk, ct) == key
+        assert nat.decaps(dk, bad) == mlkem_ref.decaps(p, dk, bad)
 
 
 @pytest.mark.parametrize(
@@ -147,7 +144,7 @@ MLDSA_FILES = ["mldsa_44.json", "mldsa_65.json", "mldsa_87.json"]
 def test_mldsa_kat_pyref_and_native(fname):
     data = _load(fname)
     p = mldsa_ref.PARAMS[data["algorithm"]]
-    nat = native.NativeMLDSA(data["algorithm"]) if _HAVE_NATIVE else None
+    nat = native.NativeMLDSA(data["algorithm"])
     for rec in data["tests"]:
         xi, rnd, msg = _b(rec, "xi"), _b(rec, "rnd"), _b(rec, "msg")
         m_prime = bytes([0, 0]) + msg
@@ -157,10 +154,9 @@ def test_mldsa_kat_pyref_and_native(fname):
         sig = mldsa_ref.sign_internal(p, sk, m_prime, rnd)
         _check(rec, "sig", sig)
         assert mldsa_ref.verify_internal(p, pk, m_prime, sig)
-        if nat is not None:
-            assert nat.keygen(xi) == (pk, sk)
-            assert nat.sign_internal(sk, m_prime, rnd) == sig
-            assert nat.verify_internal(pk, m_prime, sig)
+        assert nat.keygen(xi) == (pk, sk)
+        assert nat.sign_internal(sk, m_prime, rnd) == sig
+        assert nat.verify_internal(pk, m_prime, sig)
 
 
 @pytest.mark.parametrize(
@@ -213,8 +209,6 @@ SLHDSA_FILES = [
 
 @pytest.mark.parametrize("fname", SLHDSA_FILES)
 def test_slhdsa_kat_native(fname):
-    if not _HAVE_NATIVE:
-        pytest.skip("no C++ toolchain")
     data = _load(fname)
     nat = native.NativeSLHDSA(data["algorithm"])
     for rec in data["tests"]:
@@ -284,8 +278,6 @@ FRODO_FILES = [
 
 @pytest.mark.parametrize("fname", FRODO_FILES)
 def test_frodo_kat_pyref(fname):
-    if "aes" in fname:
-        pytest.importorskip("cryptography")  # AES matrix expansion
     data = _load(fname)
     p = frodo_ref.PARAMS[data["algorithm"]]
     for rec in data["tests"][:1]:
@@ -375,7 +367,6 @@ def test_acvp_dropin_mlkem():
 def test_rsp_parser_roundtrip(tmp_path):
     """The .rsp stanza parser + DRBG path official FrodoKEM/Kyber KAT files
     use; proven on a generated stanza file."""
-    pytest.importorskip("cryptography")  # the DRBG is AES-256-CTR
     from quantum_resistant_p2p_tpu.utils.ctr_drbg import CtrDrbg
 
     master = CtrDrbg(bytes(range(48)))
@@ -398,7 +389,6 @@ def test_hqc_official_mismatch_diagnosis():
     assumption a failing official .rsp refutes: synthesize stanzas with
     each enumerable variant seam and assert the diagnosis names it
     (docs/correctness.md §HQC seam)."""
-    pytest.importorskip("cryptography")  # the DRBG is AES-256-CTR
     from quantum_resistant_p2p_tpu.pyref import hqc_ref
     from quantum_resistant_p2p_tpu.utils.ctr_drbg import CtrDrbg
     from tools.verify_vectors import (
@@ -465,7 +455,6 @@ def test_verify_vectors_all_families():
     """tools/verify_vectors.py over the committed vector dir: every family
     has at least a fixture exercising its official-format parser + DRBG
     seam, and everything present passes."""
-    pytest.importorskip("cryptography")  # .rsp verification drives the DRBG
     from tools.verify_vectors import verify_directory
 
     report = verify_directory(VECTOR_DIR)

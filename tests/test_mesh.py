@@ -1,7 +1,7 @@
 """Multi-chip sharding in the PRODUCTION provider path (8-device virtual mesh).
 
 The conftest pins an 8-device virtual CPU platform, so these tests exercise
-the same GSPMD partitioning a real multi-chip TPU pod would run: providers
+the same shard_map programs a real multi-chip TPU host would run: providers
 constructed with ``devices=8`` shard every device batch across the mesh via
 provider.base.mesh_dispatch (computation follows data — no collectives on the
 hot path), and results must be BIT-EXACT vs the single-device path, including
@@ -145,7 +145,6 @@ def test_sphincs_provider_mesh_verify_bit_exact():
 
 def test_messaging_constructs_with_mesh_devices(tmp_path):
     """Config knob reaches the providers through SecureMessaging."""
-    pytest.importorskip("cryptography")  # messaging pulls host HKDF/AEAD
     from quantum_resistant_p2p_tpu.app.messaging import SecureMessaging
     from quantum_resistant_p2p_tpu.net.p2p_node import P2PNode
 

@@ -10,10 +10,6 @@ import asyncio
 
 import pytest
 
-# the vault + the AES-256-GCM transport AEAD need the OpenSSL wheel; minimal
-# images run the protocol-layer coverage via tests/test_faults.py instead
-pytest.importorskip("cryptography")
-
 from quantum_resistant_p2p_tpu.app import Message, MessageStore, SecureMessaging
 from quantum_resistant_p2p_tpu.net import P2PNode
 from quantum_resistant_p2p_tpu.storage import KeyStorage

@@ -35,8 +35,7 @@ def test_sort_pairs_regs_matches_array_sort_pairs():
     assert np.array_equal(got_v, np.asarray(ref_v).T)
 
 
-def test_rej_ntt_tiles_bit_exact_vs_jnp_path(monkeypatch):
-    monkeypatch.setenv("QRP2P_PALLAS", "0")  # reference = jnp rej_ntt_poly
+def test_rej_ntt_tiles_bit_exact_vs_jnp_path():
     rng = np.random.default_rng(9)
     B = 32
     seeds = jnp.asarray(rng.integers(0, 256, (B, 34), dtype=np.uint8))
@@ -54,8 +53,7 @@ def test_rej_ntt_tiles_bit_exact_vs_jnp_path(monkeypatch):
 
 
 @pytest.mark.parametrize("eta", [2, 4])
-def test_rej_bounded_tiles_bit_exact_vs_jnp_path(eta, monkeypatch):
-    monkeypatch.setenv("QRP2P_PALLAS", "0")
+def test_rej_bounded_tiles_bit_exact_vs_jnp_path(eta):
     rng = np.random.default_rng(3 + eta)
     B = 32
     seeds = jnp.asarray(rng.integers(0, 256, (B, 66), dtype=np.uint8))
@@ -72,10 +70,9 @@ def test_rej_bounded_tiles_bit_exact_vs_jnp_path(eta, monkeypatch):
     assert np.array_equal(got, ref)
 
 
-def test_ntt_tiles_bit_exact_vs_jnp(monkeypatch):
+def test_ntt_tiles_bit_exact_vs_jnp():
     """VMEM NTT/invNTT tile functions (eager) against the jnp transforms,
     plus round-trip."""
-    monkeypatch.setenv("QRP2P_PALLAS", "0")  # reference = jnp ntt/ntt_inv
     rng = np.random.default_rng(21)
     lanes = 7
     f = rng.integers(0, mldsa.Q, (lanes, 256), dtype=np.int32)

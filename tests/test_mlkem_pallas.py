@@ -27,8 +27,7 @@ def test_sort_regs_matches_array_sort():
     assert np.array_equal(got, ref)
 
 
-def test_sample_ntt_tiles_bit_exact_vs_jnp_path(monkeypatch):
-    monkeypatch.setenv("QRP2P_PALLAS", "0")  # reference = jnp sample_ntt
+def test_sample_ntt_tiles_bit_exact_vs_jnp_path():
     rng = np.random.default_rng(7)
     B = 64
     seeds = jnp.asarray(rng.integers(0, 256, (B, 34), dtype=np.uint8))
@@ -48,9 +47,8 @@ def test_sample_ntt_tiles_bit_exact_vs_jnp_path(monkeypatch):
 
 
 @pytest.mark.parametrize("eta", [2, 3])
-def test_cbd_tiles_bit_exact_vs_jnp_path(eta, monkeypatch):
+def test_cbd_tiles_bit_exact_vs_jnp_path(eta):
     # eta=3 exercises the two-block squeeze (ML-KEM-512's eta1).
-    monkeypatch.setenv("QRP2P_PALLAS", "0")
     rng = np.random.default_rng(10 + eta)
     B = 48
     s = jnp.asarray(rng.integers(0, 256, (B, 32), dtype=np.uint8))

@@ -420,6 +420,13 @@ def test_sharded_chaos_run_zero_failed_handshakes(run, monkeypatch):
 # -- bit-exactness of placed programs (real 8-device virtual platform) --------
 
 
+def test_missing_physical_shards_raise():
+    """Asking for more chips than the platform has is an error, never a
+    quiet fall back to logical slots (conftest pins 8 virtual devices)."""
+    with pytest.raises(RuntimeError, match="need 16 devices, have 8"):
+        DeviceProgramScheduler(shards=16)
+
+
 def test_placed_kem_program_bit_exact_vs_default_device():
     """Placement changes WHERE a jitted program runs, never its bits: the
     same ML-KEM-512 keygen seeds yield identical keys on every shard of

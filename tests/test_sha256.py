@@ -41,7 +41,6 @@ def test_hmac_matches_stdlib(key_len, msg_len):
 
 @pytest.mark.parametrize("length", [32, 42, 64, 100])
 def test_hkdf_matches_cryptography(length):
-    pytest.importorskip("cryptography")
     from cryptography.hazmat.primitives import hashes
     from cryptography.hazmat.primitives.kdf.hkdf import HKDF
 

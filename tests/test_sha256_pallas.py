@@ -10,11 +10,10 @@ import hashlib
 import jax.numpy as jnp
 import numpy as np
 
-from quantum_resistant_p2p_tpu.core import sha256, sha256_pallas
+from quantum_resistant_p2p_tpu.core import keccak, sha256, sha256_pallas
 
 
-def test_compress_tiles_bit_exact_vs_jnp(monkeypatch):
-    monkeypatch.setenv("QRP2P_PALLAS", "0")  # reference = jnp compress
+def test_compress_tiles_bit_exact_vs_jnp():
     rng = np.random.default_rng(6)
     B = 64
     state = jnp.asarray(rng.integers(0, 2**32, (B, 8), dtype=np.uint32))
@@ -28,13 +27,12 @@ def test_compress_tiles_bit_exact_vs_jnp(monkeypatch):
     assert np.array_equal(got, ref)
 
 
-def test_compress_kernel_split_semantics(monkeypatch):
+def test_compress_kernel_split_semantics():
     # Exercises _compress_kernel's 12/12 hi/lo word split, ref indexing, and
     # the int32 output cast with numpy arrays standing in for VMEM refs.
     # (Pallas interpret mode is unusable here: it re-jits the unrolled body
     # and XLA-CPU's LLVM backend chokes — the same pathology documented in
     # tests/test_mlkem_pallas.py, observed even under jax.disable_jit.)
-    monkeypatch.setenv("QRP2P_PALLAS", "0")
     rng = np.random.default_rng(8)
     TS, TL = 8, 128
     state = jnp.asarray(rng.integers(0, 2**32, (TS * TL, 8), dtype=np.uint32))
@@ -59,9 +57,8 @@ def test_compress_gate_routes_through_kernel(monkeypatch):
     B = 300
     state = jnp.asarray(rng.integers(0, 2**32, (B, 8), dtype=np.uint32))
     block = jnp.asarray(rng.integers(0, 256, (B, 64), dtype=np.uint8))
-    monkeypatch.setenv("QRP2P_PALLAS", "0")
     ref = np.asarray(sha256.compress(state, block))
-    monkeypatch.setenv("QRP2P_PALLAS", "1")
+    monkeypatch.setattr(keccak, "_use_pallas", lambda: True)
     def tile_compress_words(sw, bw):
         # stand-in with the real kernel body, skipping only pallas_call
         out = sha256_pallas._compress_tiles(

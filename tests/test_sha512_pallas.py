@@ -10,7 +10,7 @@ import hashlib
 import jax.numpy as jnp
 import numpy as np
 
-from quantum_resistant_p2p_tpu.core import sha512, sha512_pallas
+from quantum_resistant_p2p_tpu.core import keccak, sha512, sha512_pallas
 
 
 def _rand_state_block(seed, b):
@@ -21,8 +21,7 @@ def _rand_state_block(seed, b):
     return sh, sl, block
 
 
-def test_compress_tiles_bit_exact_vs_jnp(monkeypatch):
-    monkeypatch.setenv("QRP2P_PALLAS", "0")  # reference = jnp compress
+def test_compress_tiles_bit_exact_vs_jnp():
     sh, sl, block = _rand_state_block(6, 64)
     rh, rl = sha512.compress((sh, sl), block)
     bh, bl = sha512._block_words(block)
@@ -36,11 +35,10 @@ def test_compress_tiles_bit_exact_vs_jnp(monkeypatch):
     assert np.array_equal(got_l, np.asarray(rl))
 
 
-def test_compress_kernel_split_semantics(monkeypatch):
+def test_compress_kernel_split_semantics():
     # Exercises _compress_kernel's 24/24 transport split, ref indexing, and
     # the int32 output cast with numpy arrays standing in for VMEM refs
     # (interpret mode unusable — see tests/test_sha256_pallas.py).
-    monkeypatch.setenv("QRP2P_PALLAS", "0")
     TS, TL = 8, 128
     sh, sl, block = _rand_state_block(8, TS * TL)
     rh, rl = sha512.compress((sh, sl), block)
@@ -60,9 +58,8 @@ def test_compress_gate_routes_through_kernel(monkeypatch):
     # the pallas flag on must produce identical state updates through the
     # transpose/reshape round-trip.
     sh, sl, block = _rand_state_block(9, 300)
-    monkeypatch.setenv("QRP2P_PALLAS", "0")
     rh, rl = (np.asarray(x) for x in sha512.compress((sh, sl), block))
-    monkeypatch.setenv("QRP2P_PALLAS", "1")
+    monkeypatch.setattr(keccak, "_use_pallas", lambda: True)
 
     def tile_compress_words(swh, swl, bwh, bwl):
         out = sha512_pallas._compress_tiles(

@@ -6,7 +6,6 @@ import time
 
 import pytest
 
-pytest.importorskip("cryptography")  # vault + audit log encrypt with AES-GCM
 
 from quantum_resistant_p2p_tpu.storage import AtomicFile, KeyStorage, SecureLogger
 from quantum_resistant_p2p_tpu.storage.key_storage import KeyStorageError

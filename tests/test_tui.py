@@ -11,7 +11,6 @@ import collections
 
 import pytest
 
-pytest.importorskip("cryptography")  # the CLI stack unlocks the AES-GCM vault
 
 from quantum_resistant_p2p_tpu.cli import CLI
 from quantum_resistant_p2p_tpu.tui import Tui, _PaneWriter, peer_rows, wrap_lines

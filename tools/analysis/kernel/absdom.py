@@ -259,6 +259,14 @@ def mod(a: IVal, b: IVal) -> IVal:
     return IVal(None, None, None, None, _tile(a, b))
 
 
+def rem(a: IVal, b: IVal) -> IVal:
+    # lax.rem takes the dividend's sign: a >= 0, q > 0 -> [0, q-1]
+    if b.lo is not None and b.lo > 0 and b.hi is not None:
+        lo = 0 if a.lo is not None and a.lo >= 0 else -(b.hi - 1)
+        return IVal.range(lo, b.hi - 1, None, _tile(a, b))
+    return IVal(None, None, None, None, _tile(a, b))
+
+
 def floordiv(a: IVal, b: IVal) -> IVal:
     if None in (a.lo, a.hi, b.lo, b.hi) or b.lo <= 0 <= b.hi:
         return IVal(None, None, None, None, _tile(a, b))

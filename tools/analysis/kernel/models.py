@@ -13,7 +13,7 @@ from __future__ import annotations
 import ast
 import dataclasses
 
-from .absdom import DTYPE_WIDTH, INT_DTYPES, Dim, IVal, dim_of, join_all
+from .absdom import DTYPE_WIDTH, INT_DTYPES, Dim, IVal, dim_of, join_all, rem
 from .interp import (TOP, BlockSpecVal, BoundMethod, BuiltinVal, ConstVal,
                      DtypeVal, Event, FuncVal, LVal, PallasVal, RangeVal,
                      StructVal, SymVal, TVal, VmapVal)
@@ -444,6 +444,20 @@ def _lax_cond(interp, args, kwargs, node, env, mod):
     return join_all(ivs) if ivs and len(ivs) == len(outs) else IVal(tile=True)
 
 
+def _lax_rem(interp, args, kwargs, node, env, mod):
+    if len(args) == 2:
+        return rem(_as_ival(args[0]), _as_ival(args[1]))
+    return IVal(tile=True)
+
+
+def _lax_shr_logical(interp, args, kwargs, node, env, mod):
+    # a logical shift of a non-negative value is the arithmetic one
+    a = _as_ival(args[0]) if args else TOP
+    if isinstance(a, IVal) and a.lo is not None and a.lo >= 0:
+        return _j_bit(ast.RShift)(interp, args, kwargs, node, env, mod)
+    return IVal(tile=True)
+
+
 def _lax_select(interp, args, kwargs, node, env, mod):
     if len(args) == 3:
         return _as_ival(args[1]).join(_as_ival(args[2]))
@@ -496,6 +510,16 @@ _ROOT_MODELS = {
     ("jax", "jit"): _jax_jit, ("jax", "vmap"): _jax_vmap,
     ("jax", "ShapeDtypeStruct"): _jax_struct,
     ("lax", "cond"): _lax_cond, ("lax", "select"): _lax_select,
+    ("lax", "rem"): _lax_rem,
+    # the elementwise lax primitives kernel bodies use in place of jnp
+    # operators (trace time): the same interval models as the operators
+    ("lax", "add"): _j_bit(ast.Add), ("lax", "sub"): _j_bit(ast.Sub),
+    ("lax", "mul"): _j_bit(ast.Mult), ("lax", "shift_left"): _j_bit(ast.LShift),
+    ("lax", "shift_right_logical"): _lax_shr_logical,
+    ("lax", "bitwise_and"): _j_bit(ast.BitAnd),
+    ("lax", "bitwise_or"): _j_bit(ast.BitOr),
+    ("lax", "bitwise_xor"): _j_bit(ast.BitXor),
+    ("lax", "min"): _j_minimum, ("lax", "max"): _j_maximum,
     ("lax", "dot_general"): _j_dot_general,
     ("pl", "pallas_call"): _pl_pallas_call, ("pl", "BlockSpec"): _pl_blockspec,
     ("functools", "reduce"): None,

@@ -72,6 +72,7 @@ SECTIONS: dict[str, list[str]] = {
         "quantum_resistant_p2p_tpu.config",
         "quantum_resistant_p2p_tpu.parallel.mesh",
         "quantum_resistant_p2p_tpu.utils.benchmarking",
+        "quantum_resistant_p2p_tpu.utils.compile_cache",
         "quantum_resistant_p2p_tpu.utils.ctr_drbg",
     ],
     "obs": [

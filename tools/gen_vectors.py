@@ -102,21 +102,16 @@ def gen_slhdsa(out: Path, counts: int) -> None:
     # against pyref (tests/test_native.py), so it generates those.
     from quantum_resistant_p2p_tpu import native
 
-    nat = native.load()
     for name, p in slhdsa_ref.PARAMS.items():
         drbg = CtrDrbg(bytes(range(48)))
         tests = []
         for i in range(counts):
             ss, sp, ps = (drbg.random_bytes(p.n) for _ in range(3))
             msg = drbg.random_bytes(40 + i)
-            if nat is not None:
-                ns = native.NativeSLHDSA(name)
-                pk, sk = ns.keygen(ss, sp, ps)
-                sig = ns.sign_internal(msg, sk)
-                assert ns.verify_internal(msg, sig, pk)
-            else:  # pragma: no cover - slow path
-                pk, sk = slhdsa_ref.keygen(p, ss, sp, ps)
-                sig = slhdsa_ref.sign_internal(p, msg, sk, None)
+            ns = native.NativeSLHDSA(name)
+            pk, sk = ns.keygen(ss, sp, ps)
+            sig = ns.sign_internal(msg, sk)
+            assert ns.verify_internal(msg, sig, pk)
             tests.append(
                 _rec(count=i, sk_seed=ss, sk_prf=sp, pk_seed=ps, msg=msg, pk=pk, sig=sig)
             )
