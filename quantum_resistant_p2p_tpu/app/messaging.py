@@ -1627,8 +1627,10 @@ class SecureMessaging:
         self._responding += 1
         self._ctr_hs_admitted.inc()  # the shed-rate SLO's "good" side
         try:
+            # outcome: "accepted" once _respond_established runs, so a
+            # refused (forged, replayed, mismatched) ke_init reads apart
             with obs_trace.span("handshake.respond", peer=peer_id[:8],
-                                kem=self.kem.name):
+                                kem=self.kem.name, outcome="rejected"):
                 await self._handle_ke_init_inner(peer_id, msg, data, message_id)
         finally:
             self._responding -= 1
@@ -1684,6 +1686,7 @@ class SecureMessaging:
         """Responder success tail, shared by the per-op and fused ke_init
         paths (contractually wire-identical): adopt the shared secret and
         send the signed ke_response."""
+        obs_trace.set_attr("outcome", "accepted")  # on handshake.respond
         self._adopt_secret(peer_id, secret)
         self.shared_keys[peer_id] = derive_message_key(
             secret, self.node_id, peer_id, self.symmetric.name

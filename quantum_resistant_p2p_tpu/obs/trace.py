@@ -90,14 +90,19 @@ class SpanContext:
 
     ``node`` is the attribution lane of the span that minted the context
     (``None`` for contexts adopted from the wire — a remote parent must
-    not pull the local child onto the remote node's lane)."""
+    not pull the local child onto the remote node's lane).  ``span`` is
+    the live local span the context belongs to (``None`` when adopted from
+    the wire): what :func:`set_attr` and :func:`stage` reach through the
+    ambient context."""
 
-    __slots__ = ("trace_id", "span_id", "node")
+    __slots__ = ("trace_id", "span_id", "node", "span")
 
-    def __init__(self, trace_id: str, span_id: str, node: str | None = None):
+    def __init__(self, trace_id: str, span_id: str, node: str | None = None,
+                 span: "Span | None" = None):
         self.trace_id = trace_id
         self.span_id = span_id
         self.node = node
+        self.span = span
 
     def __repr__(self) -> str:  # pragma: no cover - debugging aid
         return f"SpanContext({self.trace_id}/{self.span_id})"
@@ -106,23 +111,70 @@ class SpanContext:
 class Span:
     """One live timed region.  All identity fields are fixed at
     construction; the attribute dict is mutated only via :meth:`set_attr`
-    (lock-guarded: a span handle may legitimately cross the executor
-    boundary it was captured around)."""
+    and the stage methods (lock-guarded: a span handle may legitimately
+    cross the executor boundary it was captured around).
 
-    __slots__ = ("name", "context", "parent_id", "attrs", "_lock")
+    **Stages.**  :meth:`begin_stages` splits the span into named stages
+    that partition it: the first runs from the span's open, each
+    :meth:`stage` call ends the open stage and begins the next, and the
+    span's end ends the last.  Each stage's time adds up in the attribute
+    ``<stage>_ms``, so a span's stage attributes sum to its duration.  A
+    span that never began stages ignores :meth:`stage` (and so does a
+    finished one)."""
 
-    def __init__(self, name: str, context: SpanContext, parent_id: str | None,
-                 attrs: dict[str, Any]):
+    __slots__ = ("name", "context", "parent_id", "attrs", "_lock", "_clock",
+                 "t0", "_stage", "_stage_t", "_on_stage")
+
+    def __init__(self, name: str, trace_id: str, span_id: str,
+                 node: str | None, parent_id: str | None,
+                 attrs: dict[str, Any],
+                 clock: Callable[[], float] = time.perf_counter):
         self.name = name
-        self.context = context
+        self.context = SpanContext(trace_id, span_id, node, self)
         self.parent_id = parent_id
         self.attrs = attrs
         self._lock = threading.Lock()
+        self._clock = clock
+        self.t0 = clock()
+        self._stage: str | None = None
+        self._stage_t = 0.0
+        self._on_stage: Callable[[str | None], None] | None = None
 
     def set_attr(self, key: str, value: Any) -> None:
         """Attach one more public attribute to the span."""
         with self._lock:
             self.attrs[key] = value
+
+    def begin_stages(self, first: str,
+                     on_stage: Callable[[str | None], None] | None = None
+                     ) -> None:
+        """Split this span into stages, ``first`` from the span's open.
+        ``on_stage(name)`` is called at each change of stage, on the thread
+        that made it: with ``first`` now, each new stage's name, and None
+        when the span ends."""
+        with self._lock:
+            self._stage, self._stage_t = first, self.t0
+            self._on_stage = on_stage
+        if on_stage is not None:
+            on_stage(first)
+
+    def stage(self, name: str | None, t: float | None = None) -> None:
+        """End the open stage and begin ``name`` at ``t`` (the tracer's
+        clock, now by default); nothing if ``name`` is already open, or the
+        span has no stages.  None ends the last stage: the span's end does
+        so."""
+        with self._lock:
+            if self._stage is None or self._stage == name:
+                return
+            if t is None:
+                t = self._clock()
+            attr = f"{self._stage}_ms"
+            self.attrs[attr] = (self.attrs.get(attr, 0.0)
+                                + 1e3 * (t - self._stage_t))
+            self._stage, self._stage_t = name, t
+            on_stage = self._on_stage
+        if on_stage is not None:
+            on_stage(name)
 
 
 class Tracer:
@@ -185,10 +237,9 @@ class Tracer:
         node = _NODE.get()
         if node is None and parent is not None:
             node = parent.node
-        ctx = SpanContext(trace_id, self._new_id(), node)
-        sp = Span(name, ctx, parent_id, dict(attrs))
-        token = _CURRENT.set(ctx)
-        t0 = self._clock()
+        sp = Span(name, trace_id, self._new_id(), node, parent_id,
+                  dict(attrs), self._clock)
+        token = _CURRENT.set(sp.context)
         try:
             yield sp
         except BaseException as exc:
@@ -196,7 +247,9 @@ class Tracer:
             raise
         finally:
             _CURRENT.reset(token)
-            self._finish(sp, t0, self._clock() - t0)
+            t1 = self._clock()
+            sp.stage(None, t1)
+            self._finish(sp, sp.t0, t1 - sp.t0)
 
     def _finish(self, sp: Span, t0: float, dur: float) -> None:
         with sp._lock:
@@ -252,6 +305,26 @@ def current() -> SpanContext | None:
     """The ambient span context — capture on the loop side, pass as
     ``parent=`` on the far side of an executor/thread hop."""
     return _CURRENT.get()
+
+
+def _ambient_span() -> Span | None:
+    ctx = _CURRENT.get()
+    return ctx.span if ctx is not None else None
+
+
+def set_attr(name: str, value: Any) -> None:
+    """Attach an attribute to the ambient span (nothing outside a span)."""
+    sp = _ambient_span()
+    if sp is not None:
+        sp.set_attr(name, value)
+
+
+def stage(name: str) -> None:
+    """Begin stage ``name`` of the ambient span (:meth:`Span.stage`):
+    nothing outside a span or in one that has no stages."""
+    sp = _ambient_span()
+    if sp is not None:
+        sp.stage(name)
 
 
 @contextlib.contextmanager
@@ -413,16 +486,3 @@ def export_spans(path: str | Path, node: str = "",
     Path(path).write_text(json.dumps(doc))
     return doc
 
-
-@contextlib.contextmanager
-def device_trace(log_dir: str = "/tmp/qrp2p_trace"):
-    """Profile everything inside the block with ``jax.profiler``; view with
-    TensorBoard.  (Moved here from ``utils.profiling`` in PR 5; the
-    deprecation shim at the old path has since been removed.)"""
-    import jax
-
-    jax.profiler.start_trace(log_dir)
-    try:
-        yield log_dir
-    finally:
-        jax.profiler.stop_trace()

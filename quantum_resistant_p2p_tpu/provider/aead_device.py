@@ -25,8 +25,9 @@ import threading
 
 import numpy as np
 
+from ..obs import trace as obs_trace
 from ..utils import next_pow2
-from .base import BatchedAEADOps
+from .base import BatchedAEADOps, ready
 
 
 class ChaChaPolyDevice(BatchedAEADOps):
@@ -96,12 +97,14 @@ class ChaChaPolyDevice(BatchedAEADOps):
         a_bucket = self._aad_bucket(max((len(a) for a in aads), default=1))
         data, lens = self._pack(data_items, l_bucket)
         aad_arr, aad_lens = self._pack(aads, a_bucket)
+        obs_trace.stage("run")
         out, tags = self._core.aead_core(
             np.ascontiguousarray(keys, dtype=np.uint8),
             np.ascontiguousarray(nonces, dtype=np.uint8),
             data, lens, aad_arr, aad_lens, seal=seal,
             use_pallas=self.use_pallas, interpret=self.interpret,
         )
+        ready((out, tags))
         with self._shape_lock:
             self.compiled_shapes.add((seal, len(data_items), l_bucket,
                                       a_bucket))

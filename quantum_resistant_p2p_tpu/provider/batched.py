@@ -24,6 +24,7 @@ import asyncio
 import functools
 import logging
 import os
+import sys
 import time
 from dataclasses import dataclass, field
 from typing import Any, Callable
@@ -87,8 +88,6 @@ class QueueStats:
     #: bound the handshake's latency, so they are counted, not inferred —
     #: see docs/dispatch_budget.md)
     device_trips: int = 0
-    #: per-flush batch sizes, most recent last (bounded)
-    batch_sizes: list[int] = field(default_factory=list)
     #: per-flush dispatch latency percentiles (obs.metrics) — measured
     #: from the event loop, so queue-wait/executor contention included
     dispatch_hist: LatencyHistogram = field(default_factory=LatencyHistogram)
@@ -100,7 +99,6 @@ class QueueStats:
     #: ops submitted / shed per priority lane (lane tag -> count)
     lane_ops: dict = field(default_factory=dict)
     lane_sheds: dict = field(default_factory=dict)
-    BATCH_SIZE_HISTORY = 1024
 
     def as_dict(self) -> dict[str, Any]:
         h = self.dispatch_hist
@@ -739,7 +737,8 @@ class OpQueue:
         )
 
     def _traced_call(self, fn, span_name: str, route: str, parent,
-                     items: list[Any], shard=None) -> list[Any]:
+                     items: list[Any], shard=None,
+                     first_t: float | None = None) -> list[Any]:
         """Run one dispatch callable inside a span, ON the worker thread —
         so the span measures the actual device/fallback time and carries
         the worker's thread lane in the flame graph.  ``parent`` is the
@@ -747,18 +746,36 @@ class OpQueue:
         not cross ``run_in_executor``).  With a ``shard``, the call runs
         under that shard's placement context (Shard.run_placed) and the
         span carries the shard index — the flame graph shows which chip
-        served each dispatch."""
+        served each dispatch.
+
+        A dispatch that runs a device program (every route but
+        ``fallback`` and ``warmup``) is split into stages (obs/trace.py
+        ``Span.begin_stages``): ``pack`` from the worker's start until the
+        program is launched, ``run`` until its outputs are ready, and
+        ``unpack`` until the batch fn returns (each program call marks the
+        changes through the ambient span: provider/base.py ``ready``), each
+        mirrored as a profiler annotation (:class:`_StageAnnotations`).
+        Such a span also carries ``queued_ms``, from the flush's first
+        submit (``first_t``) to the worker's start, and ``cpu_ms``, the
+        worker thread's CPU time over the batch fn."""
         attrs = {"op": self.label, "n": len(items), "route": route}
         if shard is not None:
             attrs["shard"] = shard.index
-        with obs_trace.span(span_name, parent=parent, **attrs):
+        device = route not in ("fallback", "warmup")
+        with obs_trace.span(span_name, parent=parent, **attrs) as sp:
             t0 = time.perf_counter()
+            if device:
+                if first_t is not None:
+                    sp.set_attr("queued_ms", 1e3 * (t0 - first_t))
+                sp.begin_stages("pack", _StageAnnotations(self.label))
+                cpu0 = time.thread_time()
             try:
                 if shard is not None:
                     return shard.run_placed(fn, items)
                 return fn(items)
             finally:
-                if route not in ("fallback", "warmup"):
+                if device:
+                    sp.set_attr("cpu_ms", 1e3 * (time.thread_time() - cpu0))
                     # on-worker DEVICE-program time (no executor queueing):
                     # the autotuner's amortization signal.  Fallback and
                     # warmup-compile durations must not pollute it — a
@@ -820,11 +837,14 @@ class OpQueue:
         return None, self.breaker.acquire_dispatch(), self.breaker
 
     async def _run_batch(self, items: list[Any], flush_span=None,
-                         lane: int | None = None) -> list[Any]:
+                         lane: int | None = None,
+                         first_t: float | None = None) -> list[Any]:
         """Device path with watchdog + breaker; falls back to cpu when the
         device is slow, hung, or raising.  Each flush is placed whole on
         one shard (when a scheduler is armed) — a flush never splits
-        across shards, so results stay bit-exact vs. the single path."""
+        across shards, so results stay bit-exact vs. the single path.
+        ``first_t`` is the flush's first submit (``perf_counter``), the
+        start of the dispatch span's ``queued_ms``."""
         loop = asyncio.get_running_loop()
         if self.fallback_fn is None:
             shard = self.scheduler.place() if self.scheduler is not None else None
@@ -837,7 +857,7 @@ class OpQueue:
                     shard.breaker.device_executor if shard is not None else None,
                     self._traced_call, self._direct_fn(shard, lane),
                     "device.dispatch", "direct", obs_trace.current(), items,
-                    shard,
+                    shard, first_t,
                 )
             finally:
                 if shard is not None:
@@ -847,7 +867,7 @@ class OpQueue:
             flush_span.set_attr("shard", shard.index)
         try:
             return await self._run_claimed(loop, items, shard, claim, breaker,
-                                           lane)
+                                           lane, first_t)
         finally:
             if shard is not None:
                 self.scheduler.done(shard)
@@ -878,8 +898,8 @@ class OpQueue:
         )
 
     async def _run_claimed(self, loop, items: list[Any], shard, claim: str,
-                           breaker: Breaker,
-                           lane: int | None = None) -> list[Any]:
+                           breaker: Breaker, lane: int | None = None,
+                           first_t: float | None = None) -> list[Any]:
         if claim == "fallback":
             return await self._run_fallback(items, breaker)
         bucket = max(self.bucket_floor, _next_pow2(len(items)))
@@ -962,7 +982,7 @@ class OpQueue:
         device = loop.run_in_executor(
             breaker.device_executor, self._traced_call,
             self._direct_fn(shard, lane), "device.dispatch", claim,
-            obs_trace.current(), items, shard,
+            obs_trace.current(), items, shard, first_t,
         )
         try:
             results = await asyncio.wait_for(
@@ -994,8 +1014,6 @@ class OpQueue:
                         first_t: float, lane: int = LANE_HANDSHAKE) -> None:
         self.stats.flushes += 1
         self.stats.max_batch_seen = max(self.stats.max_batch_seen, len(items))
-        self.stats.batch_sizes.append(len(items))
-        del self.stats.batch_sizes[: -QueueStats.BATCH_SIZE_HISTORY]
         self.stats.total_wait_s += time.perf_counter() - first_t
         t0 = time.perf_counter()
         try:
@@ -1008,7 +1026,7 @@ class OpQueue:
                                     1e3 * (t0 - first_t), 3)) as sp:
                 # _run_batch stamps the placed shard onto this span, so the
                 # flame graph's flush lane names the chip that served it
-                results = await self._run_batch(items, sp, lane)
+                results = await self._run_batch(items, sp, lane, first_t)
             dt = time.perf_counter() - t0
             self.stats.total_dispatch_s += dt
             self.stats.dispatch_hist.record(dt)
@@ -1029,6 +1047,37 @@ class OpQueue:
             for f in futs:
                 if not f.cancelled():
                     f.set_exception(exc)
+
+
+#: the profiler's names of a device dispatch's stages, each followed by
+#: the queue's label (docs/observability.md)
+STAGE_ANNOTATIONS = {"pack": "qrp2p.host.pack", "run": "qrp2p.device.run",
+                     "unpack": "qrp2p.host.unpack"}
+
+
+class _StageAnnotations:
+    """A dispatch span's stages as ``jax.profiler.TraceAnnotation``s on the
+    worker thread (the ``on_stage`` hook of ``Span.begin_stages``), so a
+    profiler trace holds them on the device trace's clock.  Inactive
+    annotations cost a name and a constructor call.  A process that has
+    not imported JAX runs no device program and has no profiler, so it
+    annotates nothing."""
+
+    __slots__ = ("label", "_open")
+
+    def __init__(self, label: str):
+        self.label = label
+        self._open = None
+
+    def __call__(self, stage: str | None) -> None:
+        if self._open is not None:
+            self._open.__exit__(None, None, None)
+            self._open = None
+        profiler = getattr(sys.modules.get("jax"), "profiler", None)
+        if stage is not None and profiler is not None:
+            self._open = profiler.TraceAnnotation(
+                f"{STAGE_ANNOTATIONS[stage]} {self.label}")
+            self._open.__enter__()
 
 
 def _run_valid(items, is_valid, dispatch, invalid_result, floor=1):

@@ -18,9 +18,10 @@ import os
 
 import numpy as np
 
+from ..obs import trace as obs_trace
 from ..pyref import frodo_ref, hqc_ref, mlkem_ref
 from .base import (KeyExchangeAlgorithm, expect_cols, expect_len,
-                   make_provider_mesh, sliced_dispatch, try_native)
+                   make_provider_mesh, ready, sliced_dispatch, try_native)
 
 _LEVEL_TO_MLKEM = {1: mlkem_ref.MLKEM512, 3: mlkem_ref.MLKEM768, 5: mlkem_ref.MLKEM1024}
 
@@ -130,11 +131,13 @@ class MLKEMKeyExchange(KeyExchangeAlgorithm):
                 # precompute is a pure hoist, tests/test_fused.py).
                 pkb = pks[0].tobytes()
                 pre = self.opcache.lookup("ek", pkb)
+                obs_trace.stage("run")
                 if pre is None:
                     pre, key, ct = self._enc_cold(pks[0], m)
                     self.opcache.put("ek", pkb, pre)
                 else:
                     key, ct = self._enc_pre(pre, m)
+                ready((key, ct))
                 return np.asarray(ct), np.asarray(key)
             key, ct = sliced_dispatch(self._enc, self._max_dispatch,
                                       pks, m, mesh=self._mesh)
@@ -271,11 +274,13 @@ class FrodoKEMKeyExchange(KeyExchangeAlgorithm):
                 # is a pure hoist, tests/test_frodo_pallas.py).
                 pkb = pks[0].tobytes()
                 pre = self.opcache.lookup("pk", pkb)
+                obs_trace.stage("run")
                 if pre is None:
                     pre, ct, ss = self._enc_cold(pks[0], mu)
                     self.opcache.put("pk", pkb, pre)
                 else:
                     ct, ss = self._enc_pre(pre, mu)
+                ready((ct, ss))
                 return np.asarray(ct), np.asarray(ss)
             return sliced_dispatch(self._enc, self._max_dispatch,
                                    pks, mu, mesh=self._mesh)

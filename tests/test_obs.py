@@ -158,7 +158,6 @@ def test_profiling_shim_is_gone():
     h = obs_metrics.LatencyHistogram()
     h.record(0.5)
     assert h.summary()["count"] == 1 and h.percentile(50) == 0.5
-    assert callable(obs_trace.device_trace)
 
 
 # -- span propagation ---------------------------------------------------------
@@ -223,6 +222,49 @@ def test_span_error_attribute_and_nesting_restored():
     assert obs_trace.current() is None  # context restored after the raise
     (rec,) = tr.snapshot()
     assert rec["attrs"]["error"] == "ValueError"
+
+
+def test_span_stages_partition_the_span():
+    """Stages split a span from its open to its end: each stage's time
+    adds up in ``<stage>_ms``, a repeated stage keeps running, and the
+    hook sees every change on the thread that made it."""
+    tr = Tracer(clock=_fake_clock(0.001))  # every reading 1 ms on
+    seen = []
+    with tr.span("d") as sp:                        # open at 0
+        sp.begin_stages("pack", seen.append)
+        obs_trace.stage("run")                      # 1
+        obs_trace.stage("run")                      # still run
+        obs_trace.stage("unpack")                   # 2
+        obs_trace.stage("run")                      # 3
+        obs_trace.stage("unpack")                   # 4
+    (rec,) = tr.snapshot()                          # end at 5
+    a = rec["attrs"]
+    assert seen == ["pack", "run", "unpack", "run", "unpack", None]
+    assert a["pack_ms"] == pytest.approx(1.0)
+    assert a["run_ms"] == pytest.approx(2.0)
+    assert a["unpack_ms"] == pytest.approx(2.0)
+    assert rec["dur"] == pytest.approx(0.005)
+    assert (a["pack_ms"] + a["run_ms"] + a["unpack_ms"]
+            == pytest.approx(rec["dur"] * 1e3, abs=1e-9))
+    # a span with no stages, a finished one and no span at all ignore it
+    with tr.span("plain") as plain:
+        obs_trace.stage("run")
+    sp.stage("run")
+    obs_trace.stage("run")
+    assert not any(k.endswith("_ms") for k in tr.snapshot()[-1]["attrs"])
+    assert seen[-1] is None and len(seen) == 6
+    assert plain.attrs == {}
+
+
+def test_ambient_set_attr_reaches_the_innermost_span():
+    tr = Tracer()
+    obs_trace.set_attr("outcome", "x")  # no span: nothing happens
+    with tr.span("outer", outcome="rejected"):
+        obs_trace.set_attr("outcome", "accepted")
+        with tr.span("inner"):
+            obs_trace.set_attr("k", 1)
+    recs = {r["name"]: r["attrs"] for r in tr.snapshot()}
+    assert recs == {"outer": {"outcome": "accepted"}, "inner": {"k": 1}}
 
 
 def test_chrome_trace_export_golden():
@@ -615,6 +657,7 @@ def test_two_node_handshake_joins_one_trace(run, monkeypatch):
     (initiate,) = by_name["handshake.initiate"]
     (respond,) = by_name["handshake.respond"]
     (confirm,) = by_name["handshake.confirm"]
+    assert respond["attrs"]["outcome"] == "accepted"
     # one causal chain across both peers
     assert respond["trace_id"] == initiate["trace_id"]
     assert confirm["trace_id"] == initiate["trace_id"]
@@ -636,6 +679,26 @@ def test_two_node_handshake_joins_one_trace(run, monkeypatch):
                 if s["attrs"]["msg_type"].startswith("ke_")]
     assert ke_recvs and all(s["trace_id"] == initiate["trace_id"]
                             and s["parent_id"] for s in ke_recvs)
+
+
+def test_refused_ke_init_reads_rejected(run, monkeypatch):
+    """A ke_init whose signature the responder refuses ends its
+    ``handshake.respond`` span with ``outcome`` rejected."""
+    monkeypatch.setattr(messaging_mod, "KEY_EXCHANGE_TIMEOUT", 2.0)
+
+    async def main():
+        a, b = await _toy_pair()
+        b.signature.verify = lambda *args: False
+        obs_trace.TRACER.reset()
+        assert not await a.initiate_key_exchange("bob")
+        spans = obs_trace.TRACER.snapshot()
+        await a.node.stop()
+        await b.node.stop()
+        return spans
+
+    responds = _spans_by_name(run(main()))["handshake.respond"]
+    assert responds and all(s["attrs"]["outcome"] == "rejected"
+                            for s in responds)
 
 
 def test_propagation_optout_restores_disjoint_traces(run, monkeypatch):
